@@ -21,7 +21,9 @@ Run from the repo root (CI does):
 
     python examples/gateway_smoke.py
 
-Exits non-zero on any mismatch.
+Exits non-zero on any mismatch. This process never imports JAX: the
+offline tokens come from a child (``--offline``) that exits before the
+gateway boots, so on an accelerator each process has the chip alone.
 """
 from __future__ import annotations
 
@@ -52,11 +54,21 @@ CAPACITY_FACTOR = 4.0
 
 
 def offline_tokens() -> list[int]:
-    """Greedy continuation from a plain in-process engine — the ground
-    truth the gateway must reproduce bit-for-bit.  Deliberately stays
-    on the CONTIGUOUS KV layout while the gateway serves from the
-    paged pool: matching tokens over HTTP exercises the
-    paged-vs-contiguous identity contract end to end."""
+    """Greedy continuation from a plain engine, computed in a child
+    process — the ground truth the gateway must reproduce bit-for-bit."""
+    r = subprocess.run([sys.executable, __file__, "--offline"],
+                       cwd=ROOT, capture_output=True, text=True,
+                       timeout=BOOT_TIMEOUT_S)
+    if r.returncode != 0:
+        sys.exit("offline engine failed:\n" + r.stdout + r.stderr)
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def _offline_tokens() -> list[int]:
+    """The child's side of ``offline_tokens``: a plain engine in this
+    process. Deliberately stays on the CONTIGUOUS KV layout while the
+    gateway serves from the paged pool: matching tokens over HTTP
+    exercises the paged-vs-contiguous identity contract end to end."""
     import dataclasses
 
     import jax
@@ -274,4 +286,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    if sys.argv[1:] == ["--offline"]:
+        print(json.dumps(_offline_tokens()))
+    else:
+        main()

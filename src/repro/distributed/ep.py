@@ -30,35 +30,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# jax promoted shard_map out of experimental at different versions; take
-# whichever this runtime provides
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                                  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _shard_map_norep(f, *, mesh, in_specs, out_specs):
-    """shard_map with the replication checker off: pallas_call has no
-    replication rule, so the Pallas FFN backends cannot run under the
-    default checker. The flag was renamed check_rep -> check_vma across
-    jax releases; try both."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:                                  # pragma: no cover
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-
-
 def ep_factorisation(num_experts: int, model_degree: int) -> tuple[int, int]:
     ep = math.gcd(num_experts, model_degree)
     return ep, model_degree // ep
-
-
-def make_ep_mesh(num_experts: int, *, data: int = 16, model: int = 16):
-    ep, tp = ep_factorisation(num_experts, model)
-    return jax.make_mesh((data, ep, tp), ("data", "ep", "tp"))
 
 
 # ------------------------------------------------------------ slot tables
@@ -264,7 +238,7 @@ def moe_ep_layer(x, router_w, slot_w, tables, *, mesh, num_experts: int,
     (scalars psum'd over ('data','ep')), plus ``aux_loss`` (always 0 —
     the serving hot path does not pay for the full-softmax probs)."""
     # lazy import: consumers of the slot-table helpers never pull in
-    # pallas-tpu (see kernels._compat)
+    # pallas-tpu
     from repro.kernels import ops as KOPS
     ep = mesh.shape["ep"]
     n_data = mesh.shape["data"]
@@ -283,9 +257,6 @@ def moe_ep_layer(x, router_w, slot_w, tables, *, mesh, num_experts: int,
     # the 1-device reference)
     logical_t = (x.shape[0] - pad_rows) * x.shape[1]
     impl = KOPS.resolve_impl(impl)   # fail fast on unknown backends
-    # pallas_call has no replication rule, so the Pallas backends need
-    # the shard_map checker off; 'ref' keeps the default trace-time check
-    smap = _shard_map if impl == "ref" else _shard_map_norep
     if token_mask is None:
         token_mask = jnp.ones(x.shape[:2], jnp.int32)
 
@@ -457,8 +428,10 @@ def moe_ep_layer(x, router_w, slot_w, tables, *, mesh, num_experts: int,
         dropped = jax.lax.psum(dropped, ("data", "ep"))
         return comb.reshape(b, s, d).astype(x_loc.dtype), loads, dropped
 
-    fn = smap(
-        local, mesh=mesh,
+    # the replication checker is off: pallas_call has no replication
+    # rule, and the psum'd loads/dropped are replicated by construction
+    fn = jax.shard_map(
+        local, mesh=mesh, check_vma=False,
         in_specs=(P(("data", "ep"), None, None), P(("data", "ep"), None),
                   P(), P(), P())
         + tuple(_slot_spec(k) for k in wkeys),

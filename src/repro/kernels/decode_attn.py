@@ -2,13 +2,19 @@
 a (possibly ring-buffered) KV cache — the serving hot spot of every
 decode_32k / long_500k shape.
 
-Grid (B, H, S//bs) with the cache-sequence axis innermost ("arbitrary"):
-each step streams one (bs, hd) K/V tile HBM->VMEM and maintains the
-online-softmax running (m, l, acc) in SMEM/VMEM scratch, exactly the
-flash-decoding recurrence. GQA is handled by indexing the KV head as
-h // (H // KV) in the BlockSpec index maps — no repeated-KV
-materialisation (the jnp path broadcasts; the kernel reads each KV tile
-once per query-head group).
+Grid (B, KV, S//bs) with the cache-sequence axis innermost ("arbitrary"):
+each step streams one (bs, hd) K/V tile of one KV head HBM->VMEM and
+maintains the online-softmax running (m, l, acc) of all G = H // KV
+query heads that share it in VMEM scratch — the flash-decoding
+recurrence. GQA therefore reads each KV tile once per query-head group
+and never materialises repeated KV.
+
+TPU tiling: the last two dims of every block must be divisible by
+(8, 128) or equal the array's own. The wrappers lay the operands out so
+that they are: q as (B, KV, G, hd) with a (G, hd) block, K/V as
+(B|NB, KV, S|blk, hd) with a (bs|blk, hd) block, and the key positions
+as (B|NB, 1, S|blk) with a (1, bs|blk) block (bs is a multiple of 128
+or the whole cache).
 
 Masking: slots >= kv_len are invalid (unwritten cache), and with
 window > 0 positions <= q_pos - window are masked (sliding window).
@@ -23,15 +29,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 def _kernel(scalar_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
             m_ref, l_ref, acc_ref, *, ns: int, window: int):
-    si = pl.program_id(2)
     b = pl.program_id(0)
+    si = pl.program_id(2)
     bs = k_ref.shape[0]
 
     @pl.when(si == 0)
@@ -42,13 +46,17 @@ def _kernel(scalar_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
 
     kv_len = scalar_ref[b, 0]
     q_pos = scalar_ref[b, 1]
-    q = q_ref[...].astype(jnp.float32).reshape(1, -1)   # (1, hd)
+    q = q_ref[...].astype(jnp.float32)          # (G, hd)
     k = k_ref[...].astype(jnp.float32)          # (bs, hd)
-    v = v_ref[...].astype(jnp.float32)
+    # rows past the cache end (a partial last tile) hold unspecified
+    # values; zero them so 0-weight lanes cannot turn p @ v into NaN
+    row = si * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+    v = jnp.where(row < kv_len, v_ref[...].astype(jnp.float32), 0.0)
 
-    s = (q @ k.T)                               # (1, bs)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # (G, bs)
     slot = si * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    kpos = pos_ref[...].reshape(1, bs)
+    kpos = pos_ref[...]                         # (1, bs)
     mask = (slot < kv_len) & (kpos <= q_pos)
     if window:
         mask = mask & (kpos > q_pos - window)
@@ -59,13 +67,26 @@ def _kernel(scalar_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, -1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + p @ v
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(si == ns - 1)
     def _out():
         o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)) \
-            .astype(o_ref.dtype).reshape(o_ref.shape)
+            .astype(o_ref.dtype)
+
+
+def _scalars(kv_len, q_pos, b):
+    return jnp.stack([jnp.broadcast_to(kv_len, (b,)).astype(jnp.int32),
+                      jnp.broadcast_to(q_pos, (b,)).astype(jnp.int32)],
+                     axis=1)
+
+
+def _scratch(g, hd):
+    return [pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, hd), jnp.float32)]
 
 
 def decode_attention(q, k, v, kv_pos, kv_len, q_pos, *, window: int = 0,
@@ -74,43 +95,37 @@ def decode_attention(q, k, v, kv_pos, kv_len, q_pos, *, window: int = 0,
     positions of cache slots; kv_len/q_pos: (B,). Returns (B, H, hd)."""
     b, h, hd = q.shape
     s, kv = k.shape[1], k.shape[2]
-    groups = h // kv
+    g = h // kv
     bs = min(bs, s)
     ns = pl.cdiv(s, bs)
-    scale = 1.0 / math.sqrt(hd)
-    q = (q * scale).astype(q.dtype)
-    scalars = jnp.stack([jnp.broadcast_to(kv_len, (b,)).astype(jnp.int32),
-                         jnp.broadcast_to(q_pos, (b,)).astype(jnp.int32)],
-                        axis=1)
+    q = (q * (1.0 / math.sqrt(hd))).astype(q.dtype).reshape(b, kv, g, hd)
     kernel = functools.partial(_kernel, ns=ns, window=window)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, h, ns),
+            grid=(b, kv, ns),
             in_specs=[
-                pl.BlockSpec((None, None, hd),
-                             lambda b, hh, si, sc: (b, hh, 0)),
-                pl.BlockSpec((None, bs, None, hd),
-                             lambda b, hh, si, sc:
-                             (b, si, hh // (h // kv), 0)),
-                pl.BlockSpec((None, bs, None, hd),
-                             lambda b, hh, si, sc:
-                             (b, si, hh // (h // kv), 0)),
-                pl.BlockSpec((None, bs),
-                             lambda b, hh, si, sc: (b, si)),
+                pl.BlockSpec((None, None, g, hd),
+                             lambda b, j, si, sc: (b, j, 0, 0)),
+                pl.BlockSpec((None, None, bs, hd),
+                             lambda b, j, si, sc: (b, j, si, 0)),
+                pl.BlockSpec((None, None, bs, hd),
+                             lambda b, j, si, sc: (b, j, si, 0)),
+                pl.BlockSpec((None, 1, bs),
+                             lambda b, j, si, sc: (b, 0, si)),
             ],
-            out_specs=pl.BlockSpec((None, None, hd),
-                                   lambda b, hh, si, sc: (b, hh, 0)),
-            scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32),
-                            pltpu.VMEM((1, 1), jnp.float32),
-                            pltpu.VMEM((1, hd), jnp.float32)],
+            out_specs=pl.BlockSpec((None, None, g, hd),
+                                   lambda b, j, si, sc: (b, j, 0, 0)),
+            scratch_shapes=_scratch(g, hd),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(scalars, q, k, v, kv_pos)
+    )(_scalars(kv_len, q_pos, b), q, k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3), kv_pos.reshape(b, 1, s))
+    return out.reshape(b, h, hd)
 
 
 def _paged_kernel(tab_ref, scalar_ref, q_ref, k_ref, v_ref, pos_ref,
@@ -133,43 +148,40 @@ def decode_attention_paged(q, k, v, kv_pos, block_tables, kv_len, q_pos, *,
     gather pool blocks directly — no (B, nbs*blk) materialisation.
     kv_len/q_pos: (B,). Returns (B, H, hd)."""
     b, h, hd = q.shape
-    blk, kv = k.shape[1], k.shape[2]
+    nb, blk, kv = k.shape[0], k.shape[1], k.shape[2]
+    g = h // kv
     nbs = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(hd)
-    q = (q * scale).astype(q.dtype)
+    q = (q * (1.0 / math.sqrt(hd))).astype(q.dtype).reshape(b, kv, g, hd)
     tables = jnp.asarray(block_tables, jnp.int32)
-    scalars = jnp.stack([jnp.broadcast_to(kv_len, (b,)).astype(jnp.int32),
-                         jnp.broadcast_to(q_pos, (b,)).astype(jnp.int32)],
-                        axis=1)
     kernel = functools.partial(_paged_kernel, ns=nbs, window=window)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, h, nbs),
+            grid=(b, kv, nbs),
             in_specs=[
-                pl.BlockSpec((None, None, hd),
-                             lambda b, hh, si, tab, sc: (b, hh, 0)),
-                pl.BlockSpec((None, blk, None, hd),
-                             lambda b, hh, si, tab, sc:
-                             (tab[b, si], 0, hh // (h // kv), 0)),
-                pl.BlockSpec((None, blk, None, hd),
-                             lambda b, hh, si, tab, sc:
-                             (tab[b, si], 0, hh // (h // kv), 0)),
-                pl.BlockSpec((None, blk),
-                             lambda b, hh, si, tab, sc: (tab[b, si], 0)),
+                pl.BlockSpec((None, None, g, hd),
+                             lambda b, j, si, tab, sc: (b, j, 0, 0)),
+                pl.BlockSpec((None, None, blk, hd),
+                             lambda b, j, si, tab, sc:
+                             (tab[b, si], j, 0, 0)),
+                pl.BlockSpec((None, None, blk, hd),
+                             lambda b, j, si, tab, sc:
+                             (tab[b, si], j, 0, 0)),
+                pl.BlockSpec((None, 1, blk),
+                             lambda b, j, si, tab, sc: (tab[b, si], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((None, None, hd),
-                                   lambda b, hh, si, tab, sc: (b, hh, 0)),
-            scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32),
-                            pltpu.VMEM((1, 1), jnp.float32),
-                            pltpu.VMEM((1, hd), jnp.float32)],
+            out_specs=pl.BlockSpec((None, None, g, hd),
+                                   lambda b, j, si, tab, sc: (b, j, 0, 0)),
+            scratch_shapes=_scratch(g, hd),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(tables, scalars, q, k, v, kv_pos)
+    )(tables, _scalars(kv_len, q_pos, b), q, k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3), kv_pos.reshape(nb, 1, blk))
+    return out.reshape(b, h, hd)
 
 
 def decode_attention_paged_ref(q, k, v, kv_pos, block_tables, kv_len,

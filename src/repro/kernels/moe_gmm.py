@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _gmm_kernel(gs_ref, x_ref, w_ref, o_ref, acc_ref, *, nd: int):
     """grid = (E, C//bc, F//bf, D//bd); D is innermost."""
@@ -90,11 +88,20 @@ def gmm(x, w, group_sizes, *, bc: int = 128, bf: int = 128, bd: int = 512,
             scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(group_sizes, x, w)
+
+
+def _scale_column(scales):
+    """(E, R) per-row scales -> (E, R, 1). A (bd, 1) block of the column
+    form satisfies the TPU tiling rule (last two block dims divisible by
+    (8, 128) or equal to the array's), which a (bd,) slice of the (E, R)
+    form does not; inside the kernel it broadcasts over the weight
+    tile's lanes exactly as ``s[:, None]`` would."""
+    return scales[..., None]
 
 
 def _gmm_q_kernel(gs_ref, x_ref, w_ref, s_ref, o_ref, acc_ref, *, nd: int):
@@ -115,7 +122,7 @@ def _gmm_q_kernel(gs_ref, x_ref, w_ref, s_ref, o_ref, acc_ref, *, nd: int):
 
     @pl.when(active)
     def _mm():
-        w = w_ref[...].astype(jnp.float32) * s_ref[...][:, None]
+        w = w_ref[...].astype(jnp.float32) * s_ref[...]
         acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.float32), w,
                                 preferred_element_type=jnp.float32)
 
@@ -148,19 +155,19 @@ def gmm_quant(x, wq, scales, group_sizes, *, bc: int = 128, bf: int = 128,
                              lambda e, ci, fi, di, gs: (e, ci, di)),
                 pl.BlockSpec((None, bd, bf),
                              lambda e, ci, fi, di, gs: (e, di, fi)),
-                pl.BlockSpec((None, bd),
-                             lambda e, ci, fi, di, gs: (e, di)),
+                pl.BlockSpec((None, bd, 1),
+                             lambda e, ci, fi, di, gs: (e, di, 0)),
             ],
             out_specs=pl.BlockSpec((None, bc, bf),
                                    lambda e, ci, fi, di, gs: (e, ci, fi)),
             scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(group_sizes, x, wq, scales)
+    )(group_sizes, x, wq, _scale_column(scales))
 
 
 def _ffn_kernel(gs_ref, x_ref, wg_ref, wu_ref, o_ref, accg_ref, accu_ref,
@@ -222,7 +229,7 @@ def fused_gate_up(x, w_gate, w_up, group_sizes, *, bc: int = 128,
                             pltpu.VMEM((bc, bf), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -250,8 +257,8 @@ def _ffn_q_kernel(gs_ref, x_ref, wg_ref, wgs_ref, wu_ref, wus_ref, o_ref,
     @pl.when(active)
     def _mm():
         xb = x_ref[...].astype(jnp.float32)
-        wg = wg_ref[...].astype(jnp.float32) * wgs_ref[...][:, None]
-        wu = wu_ref[...].astype(jnp.float32) * wus_ref[...][:, None]
+        wg = wg_ref[...].astype(jnp.float32) * wgs_ref[...]
+        wu = wu_ref[...].astype(jnp.float32) * wus_ref[...]
         accg_ref[...] += jnp.dot(xb, wg,
                                  preferred_element_type=jnp.float32)
         accu_ref[...] += jnp.dot(xb, wu,
@@ -285,12 +292,12 @@ def fused_gate_up_quant(x, wg_q, wg_s, wu_q, wu_s, group_sizes, *,
                              lambda e, ci, fi, di, gs: (e, ci, di)),
                 pl.BlockSpec((None, bd, bf),
                              lambda e, ci, fi, di, gs: (e, di, fi)),
-                pl.BlockSpec((None, bd),
-                             lambda e, ci, fi, di, gs: (e, di)),
+                pl.BlockSpec((None, bd, 1),
+                             lambda e, ci, fi, di, gs: (e, di, 0)),
                 pl.BlockSpec((None, bd, bf),
                              lambda e, ci, fi, di, gs: (e, di, fi)),
-                pl.BlockSpec((None, bd),
-                             lambda e, ci, fi, di, gs: (e, di)),
+                pl.BlockSpec((None, bd, 1),
+                             lambda e, ci, fi, di, gs: (e, di, 0)),
             ],
             out_specs=pl.BlockSpec((None, bc, bf),
                                    lambda e, ci, fi, di, gs: (e, ci, fi)),
@@ -298,8 +305,9 @@ def fused_gate_up_quant(x, wg_q, wg_s, wu_q, wu_s, group_sizes, *,
                             pltpu.VMEM((bc, bf), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(group_sizes, x, wg_q, wg_s, wu_q, wu_s)
+    )(group_sizes, x, wg_q, _scale_column(wg_s), wu_q,
+      _scale_column(wu_s))

@@ -41,7 +41,15 @@ def quantize_rows(w):
 
     Symmetric per-row: s_r = max(|w[..., r, :]|)/127 (1.0 for all-zero
     rows so padding rows stay exactly zero), q = round(w / s) in
-    [-127, 127]."""
+    [-127, 127].
+
+    Leading axes are quantized one (R, C) matrix at a time: the f32
+    working copies then stay one matrix large (a whole Mixtral layer
+    bank at once needs several 2 GB copies), and every value is the
+    same as the whole-array computation's."""
+    if w.ndim > 2:
+        qs, ss = zip(*(quantize_rows(m) for m in w))
+        return jnp.stack(qs), jnp.stack(ss)
     w = jnp.asarray(w, jnp.float32)
     amax = jnp.max(jnp.abs(w), axis=-1)
     scale = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)

@@ -8,6 +8,20 @@ init, and nothing here may run earlier.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis Auto-typed — the one place this
+    repo builds a mesh. Since JAX 0.9 ``make_mesh`` defaults each axis to
+    ``AxisType.Explicit``, under which sharding propagates through types
+    and indexed updates such as ``.at[...].set`` demand an explicit
+    ``out_sharding``; every model, EP and serving path here is written
+    against Auto (compiler-propagated) sharding."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None):
@@ -19,18 +33,7 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
     assert per_pod[0] * per_pod[1] == 256, "a v5e pod is 256 chips"
     mesh_shape = ((2,) + per_pod) if multi_pod else per_pod
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(mesh_shape, axes)
-
-
-def make_host_mesh():
-    """Single-process mesh over whatever devices exist (tests/examples).
-
-    NOTE: this is the ("data", "model") NON-expert mesh. The EP slot
-    data plane (distributed.ep / serving with --expert-runtime on)
-    requires the ("data", "ep", "tp") axes — use ``make_serving_mesh``;
-    a ("data", "model") mesh cannot run `moe_ep_layer` at all."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh(mesh_shape, axes)
 
 
 def make_serving_mesh(devices: int | None = None, *, ep: int | None = None,
@@ -58,7 +61,7 @@ def make_serving_mesh(devices: int | None = None, *, ep: int | None = None,
         raise ValueError(
             f"make_serving_mesh: data={data} x ep={ep} x tp={tp} "
             f"!= {n} devices")
-    return jax.make_mesh((data, ep, tp), ("data", "ep", "tp"))
+    return make_mesh((data, ep, tp), ("data", "ep", "tp"))
 
 
 def dp_axes(mesh) -> tuple:

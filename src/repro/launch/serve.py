@@ -181,10 +181,12 @@ def main(argv=None):
 
     import dataclasses
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import model as M
     from repro.serving.engine import MoElessController, ServingEngine
     from repro.serving.scheduler import GenRequest, SamplingParams
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=True)
     if args.prefill_chunk and not args.kv_block:
         raise SystemExit("--prefill-chunk needs --kv-block (chunked "

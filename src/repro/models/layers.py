@@ -12,6 +12,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 # ---------------------------------------------------------------- norms
 
@@ -199,7 +200,7 @@ def init_attention(key, cfg, dtype):
 
 def attention_block(p, cfg, x, positions, *, cache=None, cache_len=None,
                     window: int = 0, impl: str = "ref",
-                    block_tables=None, new_counts=None):
+                    block_tables=None, new_counts=None, mesh=None):
     """Full attention sublayer: qkv proj -> rope -> attention -> out proj.
 
     Without a cache this is a training/prefill pass over x: (B, S, D).
@@ -229,6 +230,8 @@ def attention_block(p, cfg, x, positions, *, cache=None, cache_len=None,
     hot spot (kernels.ops / kernels.decode_attn); 'ref'/'auto'-on-CPU
     keep the chunked jnp path. Prefill and multi-token steps always use
     the chunked path (the decode kernel is one-query-per-sequence).
+    `mesh` is the serving mesh when the step runs on one: the kernel is
+    then mapped over it with the rows split over ('data', 'ep').
     Returns (out, new_cache).
     """
     b, s, d = x.shape
@@ -285,9 +288,11 @@ def attention_block(p, cfg, x, positions, *, cache=None, cache_len=None,
         from repro.kernels import ops as KOPS
         resolved = KOPS.resolve_impl(impl)
         if resolved != "ref" and s == 1:
-            out = KOPS.decode_attention_paged_impl(
-                q[:, 0], ck, cv, kv_pos, block_tables, n_valid,
-                pos1[:, 0], window=window, impl=resolved)[:, None]
+            kernel = _rows_on_mesh(partial(
+                KOPS.decode_attention_paged_impl, window=window,
+                impl=resolved), mesh, replicated=(1, 2, 3))
+            out = kernel(q[:, 0], ck, cv, kv_pos, block_tables, n_valid,
+                         pos1[:, 0])[:, None]
         else:
             # gather each row's dense view: block i of the table holds
             # positions [i*blk, (i+1)*blk), so the view is position-
@@ -324,19 +329,40 @@ def attention_block(p, cfg, x, positions, *, cache=None, cache_len=None,
             kv_pos = kv_pos.at[rows, idx].set(pos1.astype(jnp.int32))
         n_valid = jnp.minimum(cl + s, smax)
         # kernels.ops is imported lazily so consumers of the jnp-only
-        # paths never pull in pallas-tpu (see kernels._compat)
+        # paths never pull in pallas-tpu
         from repro.kernels import ops as KOPS
         resolved = KOPS.resolve_impl(impl)
         if resolved != "ref" and s == 1:
-            out = KOPS.decode_attention_impl(
-                q[:, 0], ck, cv, kv_pos, n_valid, pos1[:, 0],
-                window=window, impl=resolved)[:, None]
+            kernel = _rows_on_mesh(partial(
+                KOPS.decode_attention_impl, window=window, impl=resolved),
+                mesh)
+            out = kernel(q[:, 0], ck, cv, kv_pos, n_valid,
+                         pos1[:, 0])[:, None]
         else:
             out = attention(q, ck, cv, pos1, kv_pos, causal=True,
                             window=window, kv_len=n_valid)
         new_cache = {"k": ck, "v": cv, "pos": kv_pos}
     out = out.reshape(b, s, h * hd) @ p["wo"]
     return out.astype(x.dtype), new_cache
+
+
+def _rows_on_mesh(kernel, mesh, replicated=()):
+    """`kernel` as a per-row program on the serving `mesh`: a Pallas
+    kernel cannot be partitioned by the compiler, so on a mesh it runs
+    under shard_map with every operand's leading (row) axis split over
+    ('data', 'ep') — the EP layer's token sharding — except the operands
+    at positions `replicated` (the shared paged pool), which each rank
+    holds whole."""
+    if mesh is None:
+        return kernel
+    rows = P(("data", "ep"))
+
+    def mapped(*args):
+        specs = tuple(P() if i in replicated else rows
+                      for i in range(len(args)))
+        return jax.shard_map(kernel, mesh=mesh, in_specs=specs,
+                             out_specs=rows, check_vma=False)(*args)
+    return mapped
 
 
 def init_attn_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):

@@ -78,7 +78,7 @@ def experts_ffn(p, x, act: str, *, group_sizes=None, impl: str = "ref"):
     quantized form routes through the dequantizing kernel family so the
     fp32 weights never materialise in HBM."""
     # lazy import: consumers of the jnp-only model paths never pull in
-    # pallas-tpu (see kernels._compat)
+    # pallas-tpu
     from repro.kernels import ops as OPS
     if group_sizes is None:
         group_sizes = jnp.full((x.shape[0],), x.shape[1], jnp.int32)

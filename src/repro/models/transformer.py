@@ -159,7 +159,9 @@ def _apply_sublayer(cfg, sub: SubLayer, p, x, positions, *, cache=None,
                                   cache_len=cache_len, window=window,
                                   impl=cfg.impl,
                                   block_tables=block_tables,
-                                  new_counts=new_counts)
+                                  new_counts=new_counts,
+                                  mesh=None if ep_ctx is None
+                                  else ep_ctx.mesh)
         if nc is not None:
             new_cache["attn"] = nc
     elif sub.mixer == "mamba":

@@ -46,12 +46,18 @@ import numpy as np
 
 from repro.core.control import (ControlPlane,  # noqa: F401 (re-export)
                                 IterationOutcome, MoElessController)
+from repro.launch.mesh import make_serving_mesh
 from repro.models import transformer as T
 from repro.obs.telemetry import NOOP
 from repro.serving.kv import PagedKVCache, SlotKVCache
 from repro.serving.scheduler import (ContinuousBatchingScheduler, GenRequest,
                                      RequestMetrics, SamplingParams,
                                      percentile_summary)
+
+
+@jax.jit
+def _count_nonfinite(logits):
+    return jnp.sum(~jnp.isfinite(logits))
 
 
 class TokenEvent(NamedTuple):
@@ -113,6 +119,8 @@ class ServeResult:
     runtime: object | None = None     # ExpertRuntime when enabled
     clock_s: float = 0.0              # final serving-clock time
     dropped_tokens: float = 0.0       # MoE capacity drops (all phases)
+    # NaN/Inf entries among the logits the batched steps sampled from
+    nonfinite_logits: int = 0
 
     def summary(self) -> dict:
         return percentile_summary(self.records)
@@ -160,6 +168,7 @@ class _Session:
         self.plen = np.zeros(rows, np.int32)
         self.prompts: dict[int, np.ndarray] = {}
         self.cow_seen = 0              # kv.cow_blocks already counted
+        self.nonfinite = 0             # non-finite sampled-from logits
         self.occupancy: list[int] = []
         self.iters = 0
         self.prefills = 0
@@ -248,6 +257,13 @@ class ServingEngine:
                 f"serving mesh must have axes ('data', 'ep', 'tp'), got "
                 f"{tuple(mesh.axis_names)} — use "
                 "launch.mesh.make_serving_mesh")
+        if mesh is not None:
+            # non-expert weights are replicated over the serving mesh
+            # (the EP layer's router is P()); placing them once keeps
+            # every jitted step from re-transferring them per call
+            from jax.sharding import NamedSharding, PartitionSpec
+            self.params = jax.device_put(
+                params, NamedSharding(mesh, PartitionSpec()))
         self._ep_mesh = mesh
         self._collect = controller is not None and cfg.is_moe
         self._step = self._get_step(self._collect)
@@ -426,8 +442,7 @@ class ServingEngine:
                     "the runtime executes ITS replica plans")
             from repro.serving.expert_runtime import ExpertRuntime
             if self._ep_mesh is None:
-                self._ep_mesh = jax.make_mesh((1, 1, 1),
-                                              ("data", "ep", "tp"))
+                self._ep_mesh = make_serving_mesh(1, ep=1)
             runtime = ExpertRuntime.for_control(
                 self.cfg, self.params, control, mesh=self._ep_mesh,
                 telemetry=self.telemetry,
@@ -622,13 +637,7 @@ class ServingEngine:
             logits, kv.cache, metrics = step_fn(
                 self.params, batch, kv.cache, lengths)
         t_sync = time.perf_counter()
-        if any(sess.temp[s] > 0 for s in sched.running):
-            toks = np.asarray(T.sample_tokens(
-                logits[:, -1], jnp.asarray(sess.temp),
-                jnp.asarray(sess.topk), jnp.asarray(sess.topp),
-                jnp.asarray(sess.seed), jnp.asarray(sess.count)))
-        else:   # all-greedy batch: skip the sampler's per-slot sort work
-            toks = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+        toks = self._fetch_tokens(sess, logits[:, -1])
         sync_s = time.perf_counter() - t_sync   # device->host token fetch
         dt = None
         if sess.control is not None and "expert_load" in metrics:
@@ -768,13 +777,7 @@ class ServingEngine:
         idx = jnp.asarray(np.maximum(counts - 1, 0))
         last = jnp.take_along_axis(logits, idx[:, None, None],
                                    axis=1)[:, 0]
-        if any(sess.temp[s] > 0 for s in sched.running):
-            toks = np.asarray(T.sample_tokens(
-                last, jnp.asarray(sess.temp), jnp.asarray(sess.topk),
-                jnp.asarray(sess.topp), jnp.asarray(sess.seed),
-                jnp.asarray(sess.count)))
-        else:
-            toks = np.asarray(jnp.argmax(last, axis=-1))
+        toks = self._fetch_tokens(sess, last)
         sync_s = time.perf_counter() - t_sync
         dt = None
         if sess.control is not None and "expert_load" in metrics:
@@ -835,6 +838,24 @@ class ServingEngine:
             tel.kv_blocks_free.set(kv.free_blocks)
         return events
 
+    @staticmethod
+    def _fetch_tokens(sess, last) -> np.ndarray:
+        """Sample every slot from its next-token logits `last` (rows, V)
+        and fetch the tokens to the host — the iteration's one
+        device->host sync, which also carries the count of non-finite
+        logits so a NaN/Inf model output is metered, not hidden behind
+        an argmax."""
+        if any(sess.temp[s] > 0 for s in sess.sched.running):
+            toks = T.sample_tokens(
+                last, jnp.asarray(sess.temp), jnp.asarray(sess.topk),
+                jnp.asarray(sess.topp), jnp.asarray(sess.seed),
+                jnp.asarray(sess.count))
+        else:   # all-greedy batch: skip the sampler's per-slot sort work
+            toks = jnp.argmax(last, axis=-1)
+        toks, bad = jax.device_get((toks, _count_nonfinite(last)))
+        sess.nonfinite += int(bad)
+        return np.asarray(toks)
+
     def _finish_req(self, req: GenRequest, t: float) -> None:
         """Record one request's terminal telemetry (finish counter +
         closing decode span / finish instant on its trace track)."""
@@ -890,7 +911,8 @@ class ServingEngine:
             wall_s=time.perf_counter() - sess.wall0, control=sess.control,
             runtime=sess.runtime, clock_s=sess.now,
             dropped_tokens=float(getattr(sess.control, "dropped_tokens",
-                                         0.0) or 0.0))
+                                         0.0) or 0.0),
+            nonfinite_logits=sess.nonfinite)
 
     # ------------------------------------------------------ trace replay
 

@@ -85,6 +85,7 @@ from repro.core.control import (MOELESS_EXEC_TIME, PlanEvent,
 from repro.core.costmodel import V5E, Hardware, derive_coeffs
 from repro.distributed.ep import EPContext, _slot_spec
 from repro.kernels import quant as QT
+from repro.launch.mesh import make_serving_mesh
 from repro.models import transformer as T
 
 
@@ -195,7 +196,7 @@ class ExpertRuntime:
         self.total_slots = num_devices * self.slots_per_device
 
         if mesh is None:
-            mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+            mesh = make_serving_mesh(1, ep=1)
         self.mesh = mesh
         self.ep = mesh.shape["ep"]
         # pad the physical bank to the next multiple of ep so the slot
@@ -361,6 +362,20 @@ class ExpertRuntime:
             self.slot_expert[layer, i.slot] = self.num_experts
             self.stats.evictions += 1
 
+    def _reclaim(self, layer: int, now: float, n: int, keep: set) -> None:
+        """Evict the `n` least recently used instances of `layer` that
+        are not in `keep`, billed until `now`. Serverless plans reach it
+        only when the slots are otherwise exhausted, where the analytic
+        pool (which has no slot cap) would keep them alive."""
+        inst = self.instances[layer]
+        idle = sorted((k for k in inst if k not in keep),
+                      key=lambda k: inst[k].last_used)
+        for key in idle[:n]:
+            i = inst.pop(key)
+            self._bill(i, now)
+            self.slot_expert[layer, i.slot] = self.num_experts
+            self.stats.evictions += 1
+
     def _alloc(self, layer: int, g: int) -> int:
         """Lowest free slot on logical device g, spilling to the
         ring-nearest device with capacity (mirrors ``plan_to_tables``)."""
@@ -425,17 +440,23 @@ class ExpertRuntime:
             self._reap(layer, t)
             inst = self.instances[layer]
             served_set = set(ev.served.iter_replicas())
+            desired = set(ev.plan.iter_replicas())
             if not ev.serverless:
                 # serverful semantics: the plan IS the deployment —
                 # replicas absent from it release their slot now
                 # (keep-alive would otherwise pin every historical
                 # placement of a periodic rebalancer forever)
-                desired = set(ev.plan.iter_replicas())
-                for key in [k for k in inst if k not in desired]:
-                    i = inst.pop(key)
-                    self._bill(i, t)
-                    self.slot_expert[layer, i.slot] = self.num_experts
-                    self.stats.evictions += 1
+                self._reclaim(layer, t, len(inst), keep=desired)
+            else:
+                # keep-alive can pin every slot with instances the plan
+                # no longer wants; reclaim the least recently used of
+                # them, as a serverless platform does under capacity
+                # pressure
+                short = sum(1 for key in desired if key not in inst) \
+                    - int(np.sum(self.slot_expert[layer]
+                                 == self.num_experts))
+                if short > 0:
+                    self._reclaim(layer, t, short, keep=desired)
             n_transfer = 0
             for key in ev.plan.iter_replicas():
                 if key in inst:

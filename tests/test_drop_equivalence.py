@@ -29,6 +29,7 @@ import pytest
 from repro.configs import get_config
 from repro.core.plan import LayerPlan, static_plan
 from repro.distributed import ep as EP
+from repro.launch.mesh import make_serving_mesh
 from repro.models import model as M
 from repro.models import moe as MOE
 from repro.models import transformer as T
@@ -52,7 +53,7 @@ def _single_replica_tables(e):
 
 
 def _ep(p, x, e, k, cf, tables=None, token_mask=None):
-    mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+    mesh = make_serving_mesh(1, ep=1)
     tables = tables if tables is not None else _single_replica_tables(e)
     with mesh:
         slot_w = EP.materialise_slots(p["experts"], tables["slot_expert"],
@@ -119,7 +120,7 @@ def test_capacity_factor_is_required():
     with pytest.raises(TypeError):
         MOE.dispatch_moe(p, x, top_k=k, num_experts=e, impl="ref")
     tables = _single_replica_tables(e)
-    mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+    mesh = make_serving_mesh(1, ep=1)
     with mesh:
         slot_w = EP.materialise_slots(p["experts"], tables["slot_expert"],
                                       mesh)

@@ -14,9 +14,10 @@ from repro.distributed import ep as EP
 from repro.core.plan import static_plan
 from repro.core.scaler import scale_layer
 from repro.core.placer import place_layer
+from repro.launch.mesh import make_serving_mesh
 
 E, D, F, TOPK = 4, 32, 64, 2
-mesh = jax.make_mesh((2, 2, 2), ("data", "ep", "tp"))
+mesh = make_serving_mesh(8, ep=2, tp=2, data=2)
 key = jax.random.PRNGKey(0)
 ks = jax.random.split(key, 5)
 x = jax.random.normal(ks[0], (4, 8, D), jnp.float32)

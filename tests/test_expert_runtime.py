@@ -28,6 +28,7 @@ from repro.core.plan import LayerPlan, static_plan
 from repro.core.scaler import scale_layer
 from repro.core.serverless import ServerlessExpertPool
 from repro.distributed import ep as EP
+from repro.launch.mesh import make_serving_mesh
 from repro.models import model as M
 from repro.serving.engine import ServingEngine
 from repro.serving.expert_runtime import ExpertRuntime
@@ -215,6 +216,33 @@ class TestPoolParity:
         # each swap rewrites every slot — locality can't help here, but
         # nothing leaks and nothing crashes
         assert rt.stats.evictions > 0
+
+    def test_serverless_churn_reclaims_lru_slots(self, cfg_params):
+        """Regression: under serverless keep-alive, instances the new
+        plan no longer wants can pin every slot; the runtime used to
+        raise "no free slot". It now evicts the least recently used of
+        them (billed until now) and keeps the plan's warm replicas."""
+        cfg, params = cfg_params
+        rt = ExpertRuntime(cfg, params, num_devices=2, slots_per_device=2,
+                           keep_alive=60.0)
+        e = rt.num_experts
+        plan_a = static_plan(e, 2)                       # e on device e%2
+        plan_b = LayerPlan(e, 2, np.ones(e, np.int64),   # devices swapped
+                           [[(ei + 1) % 2] for ei in range(e)])
+
+        def events(plan):
+            return [PlanEvent(plan=plan, served=plan, lead_time=math.inf,
+                              exec_time=MOELESS_EXEC_TIME, serverless=True)
+                    for _ in range(rt.n_layers)]
+
+        rt.apply(0.0, events(plan_a))                    # all 4 slots
+        gbs0 = rt.stats.instance_seconds_gb
+        r = rt.apply(1.0, events(plan_b))
+        assert r.evictions == e * rt.n_layers
+        assert r.transfers == e * rt.n_layers
+        assert rt.stats.instance_seconds_gb > gbs0       # billed to t=1
+        for layer in range(rt.n_layers):
+            assert rt.residency_set(layer) == set(plan_b.iter_replicas())
 
 
 # ------------------------------------------------------- engine parity
@@ -419,7 +447,7 @@ class TestMaterialiseDiff:
                 "w_down": jax.random.normal(ks[2], (e, f, d), jnp.float32)}
 
     def test_incremental_equals_full(self):
-        mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+        mesh = make_serving_mesh(1, ep=1)
         w = self._weights()
         padded = EP.pad_expert_bank(w)
         t1 = EP.plan_to_tables(static_plan(4, 1), ep=1, slots_per_device=8)
@@ -438,7 +466,7 @@ class TestMaterialiseDiff:
                                           np.asarray(inc[k]))
 
     def test_unchanged_plan_returns_prev_banks(self):
-        mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+        mesh = make_serving_mesh(1, ep=1)
         w = self._weights()
         t1 = EP.plan_to_tables(static_plan(4, 1), ep=1, slots_per_device=8)
         full1 = EP.materialise_slots(w, t1["slot_expert"], mesh)

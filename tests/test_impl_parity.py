@@ -31,6 +31,7 @@ from repro.core.placer import place_layer
 from repro.core.plan import static_plan
 from repro.core.scaler import scale_layer
 from repro.kernels import quant as QT
+from repro.launch.mesh import make_serving_mesh
 from repro.models import model as M
 from repro.models import moe as MOE
 
@@ -82,7 +83,7 @@ def _dense_oracle(p, x, e, k):
 def _ep_path(p, x, e, k, impl):
     """The shard_map EP data plane on a 1-device ('data','ep','tp') mesh
     (exercises pack / all_to_all / grouped-FFN / combine end-to-end)."""
-    mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+    mesh = make_serving_mesh(1, ep=1)
     spd = 2 * e
     tables = EP.plan_to_tables(static_plan(e, 1), ep=1,
                                slots_per_device=spd)
@@ -186,7 +187,7 @@ def test_ep_replica_count_invariance():
     p = _params(e, key=jax.random.fold_in(KEY, 9))
     x = jax.random.normal(jax.random.fold_in(KEY, 10), (2, 8, D),
                           jnp.float32)
-    mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+    mesh = make_serving_mesh(1, ep=1)
     loads = np.array([40.0, 10.0, 5.0, 5.0])
     plans = [static_plan(e, 1),
              place_layer(loads, scale_layer(loads, max_total_replicas=7),
@@ -307,7 +308,7 @@ def test_quant_ep_path_matches_fp32():
     p = _params(e, key=jax.random.fold_in(KEY, 21))
     x = jax.random.normal(jax.random.fold_in(KEY, 22), (2, 6, D),
                           jnp.float32)
-    mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+    mesh = make_serving_mesh(1, ep=1)
     spd = 2 * e
     tables = EP.plan_to_tables(static_plan(e, 1), ep=1,
                                slots_per_device=spd)
@@ -440,7 +441,7 @@ def test_ep_replica_invariance_property(seed, loads):
     loads = np.asarray(loads)
     plan = place_layer(loads, scale_layer(loads, max_total_replicas=8),
                        1, max_replicas_per_device=2 * e)
-    mesh = jax.make_mesh((1, 1, 1), ("data", "ep", "tp"))
+    mesh = make_serving_mesh(1, ep=1)
     tables = EP.plan_to_tables(plan, ep=1, slots_per_device=2 * e)
     with mesh:
         slot_w = EP.materialise_slots(p["experts"],

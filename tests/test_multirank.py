@@ -17,7 +17,9 @@ the scenarios share a process) and prints KEY=VALUE markers:
   * slot-geometry padding when total_slots % ep != 0 (masked pad
     slots, warned, data plane still exact);
   * the double-buffered banks equal a single-buffered runtime's banks
-    after a plan-churn sequence (pending catch-up correctness).
+    after a plan-churn sequence (pending catch-up correctness);
+  * the Pallas paged decode attention, shard_mapped over the ep=4 mesh's
+    rows, equals the unmapped kernel.
 """
 import pathlib
 import re
@@ -229,6 +231,36 @@ yd, md = dispatch_moe(
     {"router": {"w_gate": rw}, "experts": weights},
     x.reshape(1, -1, D), top_k=TOPK, num_experts=E, capacity_factor=CF)
 print("DISPATCH_DROPS_EQUAL=", int(float(md["dropped"]) == d4), sep="")
+
+# ---- Pallas paged decode attention mapped over the ep=4 mesh ---------
+# a Mosaic kernel cannot be partitioned by the compiler, so on a serving
+# mesh attention_block shard_maps it over the rows; the rows' outputs
+# must match the unmapped kernel's (to f32 rounding: the output
+# projection then runs on row shards, a wrong row or table is O(1) off)
+from repro.models import layers as L
+acfg = cfg.with_(impl="pallas_interpret")
+ap = jax.tree.map(lambda a: a[0], params["layers"][0]["attn"])
+blk, nbs, B = 4, 3, 4
+nb = 1 + B * nbs
+kk = jax.random.split(jax.random.PRNGKey(11), 3)
+pool = L.init_paged_attn_cache(acfg, nb, blk, jnp.float32)
+pool["k"] = jax.random.normal(kk[0], pool["k"].shape)
+pool["v"] = jax.random.normal(kk[1], pool["v"].shape)
+tab = np.arange(1, nb).reshape(B, nbs)
+pos = np.zeros((nb, blk), np.int32)
+for r in range(B):
+    pos[tab[r]] = np.arange(nbs * blk).reshape(nbs, blk)
+pool["pos"] = jnp.asarray(pos)
+clen = jnp.asarray([3, 7, 0, 10], jnp.int32)
+xa = jax.random.normal(kk[2], (B, 1, acfg.d_model))
+outs = [L.attention_block(ap, acfg, xa, clen[:, None], cache=pool,
+                          cache_len=clen, impl=acfg.impl,
+                          block_tables=jnp.asarray(tab, jnp.int32),
+                          new_counts=jnp.ones(B, jnp.int32), mesh=m)[0]
+        for m in (None, mesh4)]
+print("ATTN_ON_MESH=", int(np.allclose(np.asarray(outs[0]),
+                                       np.asarray(outs[1]),
+                                       rtol=0, atol=1e-5)), sep="")
 print("DONE")
 """
 
@@ -300,3 +332,7 @@ def test_forced_overflow_outputs_bitwise_equal(markers):
 
 def test_dispatch_drop_equivalence_at_ep4(markers):
     assert markers["DISPATCH_DROPS_EQUAL"] == "1"
+
+
+def test_pallas_attention_rows_on_ep4_mesh(markers):
+    assert markers["ATTN_ON_MESH"] == "1"

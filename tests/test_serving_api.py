@@ -330,6 +330,27 @@ def test_submit_nan_arrival_means_now(moe_setup):
     assert len(res.records) == 1
 
 
+@pytest.mark.parametrize("poison", [False, True])
+def test_nonfinite_logits_are_counted(moe_setup, poison):
+    """The batched steps count NaN/Inf among the logits they sample
+    from, so a broken model surfaces in the result instead of as a
+    plausible argmax token."""
+    cfg, params = moe_setup
+    if poison:
+        params = dict(params, final_norm=jax.tree.map(
+            lambda a: a * jnp.nan, params["final_norm"]))
+    engine = ServingEngine(cfg, params, max_len=32)
+    engine.start(num_slots=2)
+    for req in _mk_requests(cfg, [(4, 3), (5, 3)], [0.0, 0.0]):
+        engine.submit(req)
+    res = engine.run()
+    assert res.generated_tokens == 6
+    if poison:
+        assert res.nonfinite_logits > 0
+    else:
+        assert res.nonfinite_logits == 0
+
+
 def test_oversized_request_rejected_handle(moe_setup):
     cfg, params = moe_setup
     engine = ServingEngine(cfg, params, max_len=16)
