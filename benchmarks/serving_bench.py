@@ -486,11 +486,10 @@ _DETERMINISTIC_TELEMETRY_SERIES = frozenset({
     "runtime_replica_starts_total", "runtime_transfers_total",
     "runtime_bytes_moved_total", "runtime_rank_bytes_total",
     "runtime_evictions_total", "runtime_overlap_copies_total",
-    "runtime_overlap_hidden_seconds_total",
     "runtime_bank_flush_seconds_count",
     "control_iterations_total", "control_dropped_tokens_total",
     "control_stragglers_total", "control_pred_load_l1_error",
-    "control_layer_latency_seconds_count",
+    "control_step_seconds_count",
 })
 
 

@@ -181,7 +181,7 @@ REQUIRED_FAMILIES = {
     "runtime": ("runtime_replica_starts_total", "runtime_resident_replicas",
                 "runtime_bank_flush_seconds"),
     "control": ("control_iterations_total", "control_pred_load_l1_error",
-                "control_layer_latency_seconds"),
+                "control_step_seconds"),
     "router": ("router_requests_total", "router_replicas",
                "router_http_request_seconds"),
 }

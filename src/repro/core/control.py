@@ -29,6 +29,7 @@ plane — the scale/place/meter loop is NOT duplicated there.
 from __future__ import annotations
 
 import math
+import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -273,9 +274,25 @@ class ControlPlane:
         token_mask semantics); `dropped` (Lm,) is the data plane's
         per-layer dropped-token count, fetched inside the iteration's
         single host sync and accumulated into ``dropped_tokens`` /
-        ``phase_dropped``."""
-        pred, acts, drp = self._loads(gate_inputs, actual_loads,
-                                      token_mask, dropped)
+        ``phase_dropped``. Timed on the host clock as the section
+        ``control.step`` (children ``control.loads``, the load fetch
+        with its host sync, and ``control.plan``, the per-layer
+        scale/place/meter loop) and the ``control_step_seconds``
+        histogram."""
+        tel = self.telemetry
+        with tel.section("control.step", phase=phase) as sec:
+            with tel.section("control.loads"):
+                pred, acts, drp = self._loads(gate_inputs, actual_loads,
+                                              token_mask, dropped)
+            with tel.section("control.plan"):
+                out = self._plan(t, pred, acts, drp, phase)
+        if tel.enabled:
+            tel.control_step_seconds.observe(sec.seconds)
+        return out
+
+    def _plan(self, t: float, pred, acts, drp,
+              phase: str) -> IterationOutcome:
+        """Plan + meter every MoE layer from host loads (see ``step``)."""
         tel = self.telemetry
         self.phase_iterations[phase] = \
             self.phase_iterations.get(phase, 0) + 1
@@ -323,7 +340,6 @@ class ControlPlane:
                 # the paper's Fig. 11/12 signals, per layer: predicted vs
                 # actual load L1 error, and the max/mean imbalance factor
                 # whose excess flags a straggler
-                tel.control_layer_latency.observe(t_fwd)
                 tel.control_l1_error.labels(layer=l).set(
                     float(np.abs(pred[l] - acts[l]).sum()))
                 mx = float(acts[l].max()) if acts[l].size else 0.0
@@ -334,7 +350,8 @@ class ControlPlane:
                 tel.control_load_mean.labels(layer=l).set(mean)
                 if imb > self.straggler_factor:
                     tel.control_stragglers.inc()
-                    tel.instant(self.track, "straggler", t,
+                    tel.instant(self.track, "straggler",
+                                time.perf_counter(),
                                 args={"layer": l, "imbalance": imb})
         self.iter_latency.append(total)
         self.iterations += 1

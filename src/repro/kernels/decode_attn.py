@@ -18,6 +18,10 @@ or the whole cache).
 
 Masking: slots >= kv_len are invalid (unwritten cache), and with
 window > 0 positions <= q_pos - window are masked (sliding window).
+
+Each kernel carries a fixed ``name`` and ``metadata={"kernel": name}``
+(``decode_attn``, ``decode_attn_paged``): the custom call's
+``kernel_metadata`` in a profiler trace's op text.
 """
 from __future__ import annotations
 
@@ -123,6 +127,8 @@ def decode_attention(q, k, v, kv_pos, kv_len, q_pos, *, window: int = 0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attn",
+        metadata={"kernel": "decode_attn"},
     )(_scalars(kv_len, q_pos, b), q, k.transpose(0, 2, 1, 3),
       v.transpose(0, 2, 1, 3), kv_pos.reshape(b, 1, s))
     return out.reshape(b, h, hd)
@@ -179,6 +185,8 @@ def decode_attention_paged(q, k, v, kv_pos, block_tables, kv_len, q_pos, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attn_paged",
+        metadata={"kernel": "decode_attn_paged"},
     )(tables, _scalars(kv_len, q_pos, b), q, k.transpose(0, 2, 1, 3),
       v.transpose(0, 2, 1, 3), kv_pos.reshape(nb, 1, blk))
     return out.reshape(b, h, hd)

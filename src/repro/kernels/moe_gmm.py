@@ -25,6 +25,10 @@ Weights stream HBM->VMEM tile-by-tile via BlockSpec index maps; with the
 default tiling the VMEM working set is
   x-tile 128x512x2B + w-tile 512x128x2B + acc 128x128x4B  ~= 0.33 MB
 per buffer (x2 for double buffering), comfortably inside 16 MB VMEM.
+
+Each kernel carries a fixed ``name`` and ``metadata={"kernel": name}``
+(``moe_gmm``, ``moe_gmm_quant``, ``moe_ffn``, ``moe_ffn_quant``): the
+custom call's ``kernel_metadata`` in a profiler trace's op text.
 """
 from __future__ import annotations
 
@@ -92,6 +96,8 @@ def gmm(x, w, group_sizes, *, bc: int = 128, bf: int = 128, bd: int = 512,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="moe_gmm",
+        metadata={"kernel": "moe_gmm"},
     )(group_sizes, x, w)
 
 
@@ -167,6 +173,8 @@ def gmm_quant(x, wq, scales, group_sizes, *, bc: int = 128, bf: int = 128,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="moe_gmm_quant",
+        metadata={"kernel": "moe_gmm_quant"},
     )(group_sizes, x, wq, _scale_column(scales))
 
 
@@ -233,6 +241,8 @@ def fused_gate_up(x, w_gate, w_up, group_sizes, *, bc: int = 128,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="moe_ffn",
+        metadata={"kernel": "moe_ffn"},
     )(group_sizes, x, w_gate, w_up)
 
 
@@ -309,5 +319,7 @@ def fused_gate_up_quant(x, wg_q, wg_s, wu_q, wu_s, group_sizes, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="moe_ffn_quant",
+        metadata={"kernel": "moe_ffn_quant"},
     )(group_sizes, x, wg_q, _scale_column(wg_s), wu_q,
       _scale_column(wu_s))

@@ -1,7 +1,9 @@
-"""Zero-dependency observability: metrics registry (Prometheus text
-exposition + flat dict) and Chrome trace-event tracer, tied together by
-the `Telemetry` handle threaded through the serving stack. Default is
-the no-op `NOOP` singleton — zero overhead unless explicitly enabled.
+"""Observability with no dependency beyond JAX's profiler: metrics
+registry (Prometheus text exposition + flat dict), Chrome trace-event
+tracer, and host-clock sections that also land in a JAX profiler trace,
+tied together by the `Telemetry` handle threaded through the serving
+stack. Default is the no-op `NOOP` singleton — zero overhead unless
+explicitly enabled.
 """
 from repro.obs.registry import (
     TIME_BUCKETS,

@@ -5,7 +5,13 @@ gateway router/driver/server, launchers) that carries
   * a ``MetricsRegistry`` holding the whole metric taxonomy (declared
     HERE, in one place, so names never drift between subsystems), and
   * optionally a ``Tracer`` collecting Chrome trace-event spans /
-    instants (``tracing`` is True only when a tracer is attached).
+    instants (``tracing`` is True only when a tracer is attached), and
+  * ``section(name, **args)``: the span API of the serving stack's host
+    work. A section is a ``jax.profiler.TraceAnnotation``, so it lands
+    on the host plane of a profiler trace beside the device programs it
+    launches, and, with a tracer attached, the same span (with its
+    parent section) in the Chrome JSON. Both read the host clock
+    (``time.perf_counter``); no span reads the modelled serving clock.
 
 Default is the ``NOOP`` singleton: ``enabled`` is False and every
 metric/trace call is swallowed, so un-instrumented runs (tier-1 tests,
@@ -20,6 +26,11 @@ Metric naming follows Prometheus conventions —
 section tables the full taxonomy.
 """
 from __future__ import annotations
+
+import threading
+import time
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.obs.registry import TIME_BUCKETS, MetricsRegistry
 from repro.obs.tracing import Tracer
@@ -37,6 +48,7 @@ class Telemetry:
                  tracer: Tracer | None = None):
         self.registry = MetricsRegistry() if registry is None else registry
         self.tracer = tracer
+        self._local = threading.local()     # per-thread open sections
         r = self.registry
         # ---------------------------------------------------- scheduler
         self.sched_admitted = r.counter(
@@ -55,7 +67,7 @@ class Telemetry:
             "scheduler_pending", "requests waiting for a KV slot")
         self.sched_queue_delay = r.histogram(
             "scheduler_queue_delay_seconds",
-            "arrival -> admission delay on the serving clock")
+            "host submit -> host admission delay")
         # ------------------------------------------------------- engine
         self.engine_steps = r.counter(
             "engine_steps_total", "engine iterations, by phase",
@@ -64,7 +76,8 @@ class Telemetry:
             "engine_tokens_total", "tokens generated")
         self.engine_step_seconds = r.histogram(
             "engine_step_seconds",
-            "wall time of one engine iteration, by phase",
+            "host wall time of one engine iteration (admission "
+            "included), by phase",
             labels=("phase",))
         self.engine_host_sync = r.histogram(
             "engine_host_sync_seconds",
@@ -106,16 +119,14 @@ class Telemetry:
             "runtime_overlap_copies_total",
             "slot copies by overlap class (eligible hide under compute; "
             "exposed block the next dispatch)", labels=("kind",))
-        self.runtime_overlap_hidden = r.counter(
-            "runtime_overlap_hidden_seconds_total",
-            "modeled copy seconds hidden under compute")
         self.runtime_resident = r.gauge(
             "runtime_resident_replicas",
             "expert replicas currently resident in slot banks")
         self.runtime_flush_seconds = r.histogram(
             "runtime_bank_flush_seconds",
-            "wall time to dispatch one slot-bank flush (double-buffered "
-            "scatter)")
+            "host wall time to dispatch one slot-bank flush (the "
+            "double-buffered scatters are enqueued, not awaited; their "
+            "device time is the jit_bank_update program's)")
         # ------------------------------------------------------ control
         self.control_iterations = r.counter(
             "control_iterations_total",
@@ -142,9 +153,10 @@ class Telemetry:
             "control_load_mean",
             "mean expert load of the last iteration, per layer",
             labels=("layer",))
-        self.control_layer_latency = r.histogram(
-            "control_layer_latency_seconds",
-            "modeled per-layer MoE forward latency")
+        self.control_step_seconds = r.histogram(
+            "control_step_seconds",
+            "host wall time of one control-plane step (load fetch, "
+            "scale, place)")
         # ------------------------------------------------------- router
         self.router_requests = r.counter(
             "router_requests_total",
@@ -186,10 +198,78 @@ class Telemetry:
         if self.tracer is not None:
             self.tracer.span(track, name, t0, t1, args)
 
+    def _open_sections(self) -> list:
+        """Names of this thread's open traced sections, innermost last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def section(self, name: str, **args) -> "_Section":
+        """Context manager timing one span of host work on the host
+        clock: a profiler ``TraceAnnotation`` named `name` with `args`
+        as its stats (a ``StepTraceAnnotation`` when `args` holds
+        ``step_num``), and with a tracer attached the same span in the
+        Chrome JSON on this thread's ``host/<thread>`` track, its
+        enclosing section named as ``parent``. The entered section's
+        ``seconds`` is its duration once it exits."""
+        return _Section(self, name, args)
+
     def instant(self, track: str, name: str, t: float,
                 args: dict | None = None) -> None:
         if self.tracer is not None:
             self.tracer.instant(track, name, t, args)
+
+
+class _Section:
+    """One ``Telemetry.section`` (see there)."""
+
+    __slots__ = ("tel", "name", "args", "ann", "ev", "t0", "seconds")
+
+    def __init__(self, tel: Telemetry, name: str, args: dict):
+        self.tel, self.name, self.args = tel, name, args
+        self.ev = None
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Section":
+        ann = StepTraceAnnotation if "step_num" in self.args \
+            else TraceAnnotation
+        self.ann = ann(self.name, **self.args)
+        self.ann.__enter__()
+        tracer = self.tel.tracer
+        self.t0 = time.perf_counter()
+        if tracer is not None:
+            stack = self.tel._open_sections()
+            th = threading.current_thread().name
+            args = dict(self.args, parent=stack[-1]) if stack \
+                else self.args
+            self.ev = tracer.open_span(f"host/{th}", self.name, self.t0,
+                                       args)
+            stack.append(self.name)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        self.seconds = t1 - self.t0
+        if self.ev is not None:
+            self.tel.tracer.close_span(self.ev, t1)
+            self.tel._open_sections().pop()
+        self.ann.__exit__(*exc)
+
+
+class _NoopSection:
+    """The shared disabled section: enters and exits doing nothing."""
+
+    seconds = 0.0
+
+    def __enter__(self) -> "_NoopSection":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NOOP_SECTION = _NoopSection()
 
 
 class _NoopMetric:
@@ -231,6 +311,9 @@ class NullTelemetry:
 
     def instant(self, track, name, t, args=None) -> None:
         pass
+
+    def section(self, name, **args) -> _NoopSection:
+        return _NOOP_SECTION
 
 
 NOOP = NullTelemetry()

@@ -5,12 +5,15 @@ Trace Event Format JSON that loads directly into Perfetto
 Tracks are named lanes (``"engine"``, ``"moeless/req3"``, ``"router"``)
 mapped to stable ``tid`` values with ``thread_name`` metadata so the
 viewer shows readable lane names. Timestamps are SECONDS in the
-caller's timeline — the serving stack records everything against the
-(modeled) serving clock, so a trace of a deterministic replay is itself
-deterministic — converted to the format's microseconds on emit.
+caller's timeline — the serving stack records everything on the host
+clock (``time.perf_counter``), the clock the JAX profiler's host spans
+share — converted to the format's microseconds on emit.
 
 Event kinds:
   * ``span(track, name, t0, t1)``   — a complete event (``ph: "X"``);
+  * ``open_span(track, name, t0)`` / ``close_span(ev, t1)`` — the same
+    complete event, appended when it opens so that nested spans keep
+    their start order on the track;
   * ``instant(track, name, t)``     — an instant event (``ph: "i"``);
   * ``counter(track, name, t, **v)``— a counter event (``ph: "C"``,
     rendered as a stacked area chart in the viewer).
@@ -54,6 +57,23 @@ class Tracer:
             if args:
                 ev["args"] = args
             self._events.append(ev)
+
+    def open_span(self, track: str, name: str, t0: float,
+                  args: dict | None = None, cat: str = "serving") -> dict:
+        """A complete span on `track` starting at `t0` seconds, its
+        duration set by ``close_span``. Returns the event."""
+        with self._lock:
+            ev = {"name": name, "cat": cat, "ph": "X", "pid": 0,
+                  "tid": self._tid(track), "ts": t0 * 1e6, "dur": 0.0}
+            if args:
+                ev["args"] = args
+            self._events.append(ev)
+        return ev
+
+    def close_span(self, ev: dict, t1: float) -> None:
+        """End the span `open_span` returned at `t1` seconds."""
+        with self._lock:
+            ev["dur"] = max(t1 * 1e6 - ev["ts"], 0.0)
 
     def instant(self, track: str, name: str, t: float,
                 args: dict | None = None, cat: str = "serving") -> None:

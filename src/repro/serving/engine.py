@@ -60,6 +60,13 @@ def _count_nonfinite(logits):
     return jnp.sum(~jnp.isfinite(logits))
 
 
+def _named(fn: partial, name: str) -> partial:
+    """`fn` under a fixed program name: jitted, XLA names its module
+    ``jit_<name>``, the handle a profiler trace gives its executions."""
+    fn.__name__ = name
+    return fn
+
+
 class TokenEvent(NamedTuple):
     """One generated token, as surfaced by ``ServingEngine.step``."""
     rid: int
@@ -242,7 +249,9 @@ class ServingEngine:
         # trace tracks (per-replica / per-strategy lanes).
         self.telemetry = NOOP if telemetry is None else telemetry
         self.name = name
-        self._marks: dict[int, float] = {}   # rid -> prefill-end clock t
+        # rid -> host time of the request's last lifecycle boundary
+        # (submit, admission, first token): where its next span starts
+        self._marks: dict[int, float] = {}
         self.window = window
         self.expert_runtime = expert_runtime
         self._steps: dict[bool, callable] = {}
@@ -282,9 +291,9 @@ class ServingEngine:
 
     def _get_step(self, collect: bool):
         if collect not in self._steps:
-            self._steps[collect] = jax.jit(partial(
+            self._steps[collect] = jax.jit(_named(partial(
                 T.decode_step, self.cfg, window=self.window,
-                collect=collect))
+                collect=collect), "engine_step"))
         return self._steps[collect]
 
     def _get_ep_step(self, collect: bool, ctx):
@@ -293,9 +302,9 @@ class ServingEngine:
         tables/weights are traced, so plan changes never recompile."""
         key = (collect, ctx)
         if key not in self._ep_steps:
-            self._ep_steps[key] = jax.jit(partial(
+            self._ep_steps[key] = jax.jit(_named(partial(
                 T.decode_step, self.cfg, window=self.window,
-                collect=collect, ep_ctx=ctx))
+                collect=collect, ep_ctx=ctx), "engine_step"))
         return self._ep_steps[key]
 
     def new_cache(self, batch_size: int):
@@ -460,6 +469,7 @@ class ServingEngine:
     def close(self) -> None:
         with self._lock:
             self._session = None
+            self._marks.clear()
 
     @property
     def _sess(self) -> _Session:
@@ -499,6 +509,7 @@ class ServingEngine:
             tel = self.telemetry
             if tel.enabled:
                 if ok:
+                    self._marks[req.rid] = time.perf_counter()
                     tel.sched_pending.set(len(sess.sched.pending))
                 else:
                     tel.sched_rejected.labels(reason="capacity").inc()
@@ -519,7 +530,7 @@ class ServingEngine:
                 tel.sched_cancelled.inc()
                 self._marks.pop(handle.req.rid, None)
                 tel.instant(f"{self.name}/req{handle.req.rid}", "cancel",
-                            sess.now)
+                            time.perf_counter())
             return ok
 
     def step(self) -> list[TokenEvent]:
@@ -530,16 +541,50 @@ class ServingEngine:
         Each admission and the decode step drive the control plane.
         Thread-safe; registered step hooks fire before the lock drops.
         A no-op on a closed session — a parked step-loop thread racing a
-        ``close`` must not resurrect a fresh default session."""
+        ``close`` must not resurrect a fresh default session.
+
+        Timed on the host clock as the section ``engine.step``, whose
+        children are ``engine.admit`` (scheduler pop and KV admission),
+        ``engine.prepare`` (host batch build and transfer),
+        ``engine.dispatch`` (the jitted ``engine_step`` call),
+        ``engine.fetch`` (sampling and the device->host token fetch),
+        the control plane's ``control.step`` and the expert runtime's
+        ``runtime.apply``, and ``engine.commit`` (KV advance and the
+        token loop)."""
         with self._lock:
             if self._session is None:
                 return []
-            events = self._step_impl()
+            t0 = time.perf_counter()
+            with self.telemetry.section("engine.step",
+                                        step_num=self.iteration):
+                events = self._step_impl(t0)
             for fn in list(self._step_hooks):
                 fn(events)
             return events
 
-    def _step_impl(self) -> list[TokenEvent]:
+    def _admitted(self, req: GenRequest, **args) -> None:
+        """Telemetry of one admission (host clock): the queue delay since
+        submit and the request's ``queue`` span."""
+        tel = self.telemetry
+        now = time.perf_counter()
+        t_sub = self._marks.get(req.rid, now)
+        self._marks[req.rid] = now
+        tel.sched_admitted.inc()
+        tel.sched_queue_delay.observe(now - t_sub)
+        tel.span(f"{self.name}/req{req.rid}", "queue", t_sub, now,
+                 args=dict(args, rid=req.rid))
+
+    def _prefilled(self, req: GenRequest, plen: int) -> None:
+        """The request's ``prefill`` span, admission to first token."""
+        now = time.perf_counter()
+        self.telemetry.span(
+            f"{self.name}/req{req.rid}", "prefill",
+            self._marks.get(req.rid, now), now,
+            args={"rid": req.rid, "prompt_len": plen,
+                  "prefix_hit_len": req.prefix_hit_len})
+        self._marks[req.rid] = now
+
+    def _step_impl(self, t_step0: float) -> list[TokenEvent]:
         sess = self._sess
         sched, kv = sess.sched, sess.kv
         events: list[TokenEvent] = []
@@ -554,55 +599,48 @@ class ServingEngine:
             and self.cfg.is_moe)
         tel = self.telemetry
         if self.serving.prefill_chunk > 0:
-            return self._step_chunked(sess, collect, events)
+            return self._step_chunked(sess, collect, events, t_step0)
         # admission: prefill every arrived request that fits a slot
-        while (req := sched.pop_admissible(sess.now)) is not None:
-            t0 = time.perf_counter()
-            t_admit = sess.now
-            tok, cache1, plen, metrics, mask = self.prefill_request(
-                req.prompt, collect=collect, sampling=req.sampling,
-                rid=req.rid)
-            dt = None
-            if sess.control is not None and "expert_load" in metrics:
-                out = sess.control.step(
-                    sess.now, self._gate_inputs(metrics),
-                    metrics["expert_load"], token_mask=mask,
-                    dropped=metrics.get("dropped"), phase="prefill")
-                dt = out.latency_s
-                if sess.runtime is not None:
-                    sess.runtime.apply(sess.now, out.events,
-                                       phase="prefill",
-                                       compute_s=out.latency_s)
-            self._drive_controller(metrics, token_mask=mask)
-            if dt is None:
-                dt = time.perf_counter() - t0
-            slot = kv.alloc()
-            kv.insert(slot, cache1, plen, owner=req.rid)
-            sess.bind_slot(slot, req)
-            sched.start(req, slot, sess.now)
-            sess.now += dt * sess.time_scale
-            sess.prefills += 1
-            sess.cur[slot] = tok
-            sess.count[slot] = 1
-            done = sched.on_token(slot, tok, sess.now)  # TTFT: prefill end
-            events.append(TokenEvent(req.rid, tok, done))
-            if tel.enabled:
-                tel.sched_admitted.inc()
-                tel.sched_queue_delay.observe(
-                    max(t_admit - req.arrival, 0.0))
-                tel.engine_steps.labels(phase="prefill").inc()
-                tel.engine_step_seconds.labels(phase="prefill").observe(
-                    time.perf_counter() - t0)
-                tel.engine_tokens.inc()
-                if tel.tracing:
-                    track = f"{self.name}/req{req.rid}"
-                    tel.span(track, "queue", req.arrival, t_admit)
-                    tel.span(track, "prefill", t_admit, sess.now,
-                             args={"prompt_len": plen,
-                                   "prefix_hit_len": req.prefix_hit_len})
-                    self._marks[req.rid] = sess.now
-                if done:
-                    self._finish_req(req, sess.now)
+        with tel.section("engine.admit"):
+            while (req := sched.pop_admissible(sess.now)) is not None:
+                t0 = time.perf_counter()
+                if tel.enabled:
+                    self._admitted(req)
+                tok, cache1, plen, metrics, mask = self.prefill_request(
+                    req.prompt, collect=collect, sampling=req.sampling,
+                    rid=req.rid)
+                dt = None
+                if sess.control is not None and "expert_load" in metrics:
+                    out = sess.control.step(
+                        sess.now, self._gate_inputs(metrics),
+                        metrics["expert_load"], token_mask=mask,
+                        dropped=metrics.get("dropped"), phase="prefill")
+                    dt = out.latency_s
+                    if sess.runtime is not None:
+                        sess.runtime.apply(sess.now, out.events,
+                                           phase="prefill",
+                                           compute_s=out.latency_s)
+                self._drive_controller(metrics, token_mask=mask)
+                if dt is None:
+                    dt = time.perf_counter() - t0
+                slot = kv.alloc()
+                kv.insert(slot, cache1, plen, owner=req.rid)
+                sess.bind_slot(slot, req)
+                sched.start(req, slot, sess.now)
+                sess.now += dt * sess.time_scale
+                sess.prefills += 1
+                sess.cur[slot] = tok
+                sess.count[slot] = 1
+                done = sched.on_token(slot, tok, sess.now)  # TTFT
+                events.append(TokenEvent(req.rid, tok, done))
+                if tel.enabled:
+                    tel.engine_steps.labels(phase="prefill").inc()
+                    tel.engine_step_seconds.labels(phase="prefill").observe(
+                        time.perf_counter() - t0)
+                    tel.engine_tokens.inc()
+                    self._prefilled(req, plen)
+                    if done:
+                        self._finish_req(req)
         if tel.enabled:
             tel.sched_pending.set(len(sched.pending))
         if not sched.running:
@@ -610,35 +648,22 @@ class ServingEngine:
         # one batched decode step over the whole pool (static shapes),
         # then one jitted sampling call over every slot
         t0 = time.perf_counter()
-        t_clock0 = sess.now
-        if isinstance(kv, PagedKVCache):
-            lengths, active, tables = kv.step_state()
-            batch = {"tokens": jnp.asarray(sess.cur[:, None]),
-                     "active": active, "block_tables": tables,
-                     "new_counts": active.astype(jnp.int32)}
-        else:
-            lengths, active = kv.step_lengths()
-            batch = {"tokens": jnp.asarray(sess.cur[:, None]),
-                     "active": active}
-        if sess.runtime is not None:
-            # EP slot data plane: the MoE layers execute the control
-            # plane's plans through the runtime's live slot
-            # tables/weights (re-programmed each iteration, no
-            # recompile). The KV pool's pad rows (num_slots rounded up
-            # to the shard multiple) are capacity-neutral (ctx.pad_rows)
-            step_fn = self._get_ep_step(collect, dataclasses.replace(
-                sess.runtime.ctx,
-                pad_rows=sess.kv.rows - sess.kv.num_slots))
-            logits, kv.cache, metrics = step_fn(
-                self.params, batch, kv.cache, lengths,
-                sess.runtime.ep_state())
-        else:
-            step_fn = self._get_step(collect)
-            logits, kv.cache, metrics = step_fn(
-                self.params, batch, kv.cache, lengths)
-        t_sync = time.perf_counter()
-        toks = self._fetch_tokens(sess, logits[:, -1])
-        sync_s = time.perf_counter() - t_sync   # device->host token fetch
+        with tel.section("engine.prepare"):
+            if isinstance(kv, PagedKVCache):
+                lengths, active, tables = kv.step_state()
+                batch = {"tokens": jnp.asarray(sess.cur[:, None]),
+                         "active": active, "block_tables": tables,
+                         "new_counts": active.astype(jnp.int32)}
+            else:
+                lengths, active = kv.step_lengths()
+                batch = {"tokens": jnp.asarray(sess.cur[:, None]),
+                         "active": active}
+        with tel.section("engine.dispatch", phase="decode",
+                         rows=len(sched.running), prefill_rows=0):
+            logits, kv.cache, metrics = self._dispatch(
+                sess, collect, batch, lengths)
+        with tel.section("engine.fetch") as fetch:
+            toks = self._fetch_tokens(sess, logits[:, -1])
         dt = None
         if sess.control is not None and "expert_load" in metrics:
             out = sess.control.step(
@@ -657,37 +682,62 @@ class ServingEngine:
         self.iteration += 1
         n_active = len(sched.running)
         sess.occupancy.append(n_active)
+        with tel.section("engine.commit"):
+            capped = set(kv.advance())
+            for slot in list(sched.running):
+                tok = int(toks[slot])
+                sess.cur[slot] = tok
+                sess.count[slot] += 1
+                req = sched.running[slot]
+                done = sched.on_token(slot, tok, sess.now)
+                if not done and slot in capped:
+                    # KV ring/blocks at capacity: one more decode would
+                    # overwrite live cache — finish with reason "length"
+                    sched.force_finish(slot, sess.now)
+                    done = True
+                events.append(TokenEvent(req.rid, tok, done))
+                if done and tel.enabled:
+                    self._finish_req(req)
         if tel.enabled:
-            tel.engine_steps.labels(phase="decode").inc()
-            tel.engine_step_seconds.labels(phase="decode").observe(
-                time.perf_counter() - t0)
-            tel.engine_host_sync.observe(sync_s)
-            tel.engine_occupancy.set(n_active)
+            self._stepped("decode", t0, fetch.seconds, n_active)
             tel.engine_tokens.inc(n_active)
-            if tel.tracing:
-                tel.span(self.name, "decode_step", t_clock0, sess.now,
-                         args={"occupancy": n_active})
-        capped = set(kv.advance())
-        for slot in list(sched.running):
-            tok = int(toks[slot])
-            sess.cur[slot] = tok
-            sess.count[slot] += 1
-            req = sched.running[slot]
-            done = sched.on_token(slot, tok, sess.now)
-            if not done and slot in capped:
-                # KV ring/blocks at capacity: one more decode would
-                # overwrite live cache — finish with reason "length"
-                sched.force_finish(slot, sess.now)
-                done = True
-            events.append(TokenEvent(req.rid, tok, done))
-            if done and tel.enabled:
-                self._finish_req(req, sess.now)
-        if tel.enabled and isinstance(kv, PagedKVCache):
-            tel.kv_blocks_used.set(kv.used_blocks)
-            tel.kv_blocks_free.set(kv.free_blocks)
+            if isinstance(kv, PagedKVCache):
+                tel.kv_blocks_used.set(kv.used_blocks)
+                tel.kv_blocks_free.set(kv.free_blocks)
         return events
 
-    def _step_chunked(self, sess, collect, events) -> list[TokenEvent]:
+    def _dispatch(self, sess, collect, batch, lengths):
+        """The batched step: ONE jitted ``engine_step`` over the pool."""
+        kv = sess.kv
+        if sess.runtime is not None:
+            # EP slot data plane: the MoE layers execute the control
+            # plane's plans through the runtime's live slot
+            # tables/weights (re-programmed each iteration, no
+            # recompile). The KV pool's pad rows (num_slots rounded up
+            # to the shard multiple) are capacity-neutral (ctx.pad_rows)
+            step_fn = self._get_ep_step(collect, dataclasses.replace(
+                sess.runtime.ctx, pad_rows=kv.rows - kv.num_slots))
+            return step_fn(self.params, batch, kv.cache, lengths,
+                           sess.runtime.ep_state())
+        step_fn = self._get_step(collect)
+        return step_fn(self.params, batch, kv.cache, lengths)
+
+    def _stepped(self, phase: str, t0: float, sync_s: float,
+                 n_active: int) -> None:
+        """Telemetry of one finished batched step that began (host
+        clock) at `t0`: its phase counter and seconds, the token fetch's
+        seconds, occupancy, and the engine's ``decode_step`` span."""
+        tel = self.telemetry
+        t1 = time.perf_counter()
+        tel.engine_steps.labels(phase=phase).inc()
+        tel.engine_step_seconds.labels(phase=phase).observe(t1 - t0)
+        tel.engine_host_sync.observe(sync_s)
+        tel.engine_occupancy.set(n_active)
+        tel.span(self.name, "decode_step", t0, t1,
+                 args={"occupancy": n_active, "phase": phase})
+
+    def _step_chunked(self, sess, collect, events,
+                      t_step0: float) -> list[TokenEvent]:
         """Chunked-prefill iteration (paged KV only): admission is pure
         table work — ``kv.begin`` matches the prefix cache, refcount-
         shares the matched blocks, and reserves the rest; NO solo model
@@ -696,89 +746,79 @@ class ServingEngine:
         batched step the decoding slots run, as extra masked rows — the
         decode batch never stalls behind a long prompt. A slot's first
         output token is sampled from the logits of its final prompt
-        position the step its last chunk lands."""
+        position the step its last chunk lands. `t_step0` is the host
+        time the step began, admission included."""
         sched, kv = sess.sched, sess.kv
         tel = self.telemetry
         chunk = self.serving.prefill_chunk
-        while (req := sched.pop_admissible(sess.now)) is not None:
-            slot = kv.alloc()
-            hit = kv.begin(slot, req.prompt, req.max_new_tokens,
-                           owner=req.rid)
-            req.prefix_hit_len = hit
-            sess.bind_slot(slot, req)
-            prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-            sess.prompts[slot] = prompt
-            sess.plen[slot] = prompt.shape[0]
-            sched.start(req, slot, sess.now)
-            sess.prefills += 1
-            if tel.enabled:
-                tel.sched_admitted.inc()
-                tel.sched_queue_delay.observe(
-                    max(sess.now - req.arrival, 0.0))
-                if hit:
-                    tel.kv_prefix_hits.inc()
-                    tel.kv_prefix_tokens_saved.inc(hit)
-                cow = kv.cow_blocks - sess.cow_seen
-                if cow:
-                    tel.kv_cow_copies.inc(cow)
-                if tel.tracing:
-                    tel.span(f"{self.name}/req{req.rid}", "queue",
-                             req.arrival, sess.now,
-                             args={"prefix_hit_len": hit})
-            sess.cow_seen = kv.cow_blocks
+        with tel.section("engine.admit"):
+            while (req := sched.pop_admissible(sess.now)) is not None:
+                slot = kv.alloc()
+                hit = kv.begin(slot, req.prompt, req.max_new_tokens,
+                               owner=req.rid)
+                req.prefix_hit_len = hit
+                sess.bind_slot(slot, req)
+                prompt = np.asarray(req.prompt, np.int32).reshape(-1)
+                sess.prompts[slot] = prompt
+                sess.plen[slot] = prompt.shape[0]
+                sched.start(req, slot, sess.now)
+                sess.prefills += 1
+                if tel.enabled:
+                    self._admitted(req, prefix_hit_len=hit)
+                    if hit:
+                        tel.kv_prefix_hits.inc()
+                        tel.kv_prefix_tokens_saved.inc(hit)
+                    cow = kv.cow_blocks - sess.cow_seen
+                    if cow:
+                        tel.kv_cow_copies.inc(cow)
+                sess.cow_seen = kv.cow_blocks
         if tel.enabled:
             tel.sched_pending.set(len(sched.pending))
         if not sched.running:
             return events
         t0 = time.perf_counter()
-        t_clock0 = sess.now
-        rows = kv.rows
-        counts = np.zeros(rows, np.int32)
-        first_rows: set[int] = set()   # prompt completes this step
-        any_prefill = False
-        for slot in sched.running:
-            left = int(sess.plen[slot]) - int(kv.lengths[slot])
-            if left > 0:
-                any_prefill = True
-                counts[slot] = min(chunk, left)
-                if counts[slot] == left:
-                    first_rows.add(slot)
-            else:
-                counts[slot] = 1
-        s_new = chunk if any_prefill else 1   # two jit entries total
-        tokens = np.zeros((rows, s_new), np.int32)
-        for slot in sched.running:
-            c = int(counts[slot])
-            pos = int(kv.lengths[slot])
-            if pos < sess.plen[slot]:
-                tokens[slot, :c] = sess.prompts[slot][pos:pos + c]
-            else:
-                tokens[slot, 0] = sess.cur[slot]
-        lengths, active, tables = kv.step_state()
-        counts_j = jnp.asarray(counts)
-        mask = jnp.arange(s_new, dtype=jnp.int32)[None] \
-            < counts_j[:, None]
-        batch = {"tokens": jnp.asarray(tokens), "active": active,
-                 "token_mask": mask, "block_tables": tables,
-                 "new_counts": counts_j}
-        phase = "mixed" if any_prefill else "decode"
-        if sess.runtime is not None:
-            step_fn = self._get_ep_step(collect, dataclasses.replace(
-                sess.runtime.ctx, pad_rows=kv.rows - kv.num_slots))
-            logits, kv.cache, metrics = step_fn(
-                self.params, batch, kv.cache, lengths,
-                sess.runtime.ep_state())
-        else:
-            step_fn = self._get_step(collect)
-            logits, kv.cache, metrics = step_fn(
-                self.params, batch, kv.cache, lengths)
-        t_sync = time.perf_counter()
-        # each row's next-token logits sit at its LAST written position
-        idx = jnp.asarray(np.maximum(counts - 1, 0))
-        last = jnp.take_along_axis(logits, idx[:, None, None],
-                                   axis=1)[:, 0]
-        toks = self._fetch_tokens(sess, last)
-        sync_s = time.perf_counter() - t_sync
+        with tel.section("engine.prepare"):
+            rows = kv.rows
+            counts = np.zeros(rows, np.int32)
+            first_rows: set[int] = set()   # prompt completes this step
+            for slot in sched.running:
+                left = int(sess.plen[slot]) - int(kv.lengths[slot])
+                if left > 0:
+                    counts[slot] = min(chunk, left)
+                    if counts[slot] == left:
+                        first_rows.add(slot)
+                else:
+                    counts[slot] = 1
+            n_prefill = sum(1 for slot in sched.running
+                            if kv.lengths[slot] < sess.plen[slot])
+            s_new = chunk if n_prefill else 1   # two jit entries total
+            tokens = np.zeros((rows, s_new), np.int32)
+            for slot in sched.running:
+                c = int(counts[slot])
+                pos = int(kv.lengths[slot])
+                if pos < sess.plen[slot]:
+                    tokens[slot, :c] = sess.prompts[slot][pos:pos + c]
+                else:
+                    tokens[slot, 0] = sess.cur[slot]
+            lengths, active, tables = kv.step_state()
+            counts_j = jnp.asarray(counts)
+            mask = jnp.arange(s_new, dtype=jnp.int32)[None] \
+                < counts_j[:, None]
+            batch = {"tokens": jnp.asarray(tokens), "active": active,
+                     "token_mask": mask, "block_tables": tables,
+                     "new_counts": counts_j}
+        phase = "mixed" if n_prefill else "decode"
+        with tel.section("engine.dispatch", phase=phase,
+                         rows=len(sched.running), prefill_rows=n_prefill):
+            logits, kv.cache, metrics = self._dispatch(
+                sess, collect, batch, lengths)
+        with tel.section("engine.fetch") as fetch:
+            # each row's next-token logits sit at its LAST written
+            # position
+            idx = jnp.asarray(np.maximum(counts - 1, 0))
+            last = jnp.take_along_axis(logits, idx[:, None, None],
+                                       axis=1)[:, 0]
+            toks = self._fetch_tokens(sess, last)
         dt = None
         if sess.control is not None and "expert_load" in metrics:
             out = sess.control.step(
@@ -797,42 +837,31 @@ class ServingEngine:
         self.iteration += 1
         n_active = len(sched.running)
         sess.occupancy.append(n_active)
-        if tel.enabled:
-            tel.engine_steps.labels(phase=phase).inc()
-            tel.engine_step_seconds.labels(phase=phase).observe(
-                time.perf_counter() - t0)
-            tel.engine_host_sync.observe(sync_s)
-            tel.engine_occupancy.set(n_active)
-            if tel.tracing:
-                tel.span(self.name, "decode_step", t_clock0, sess.now,
-                         args={"occupancy": n_active, "phase": phase})
-        capped = set(kv.advance(counts))
         emitted = 0
-        for slot in list(sched.running):
-            req = sched.running[slot]
-            if kv.lengths[slot] < sess.plen[slot]:
-                continue                 # still mid-prefill: no token yet
-            if slot in first_rows:
-                sess.count[slot] = 1     # the request's first token
-            else:
-                sess.count[slot] += 1
-            tok = int(toks[slot])
-            sess.cur[slot] = tok
-            emitted += 1
-            done = sched.on_token(slot, tok, sess.now)  # TTFT on first
-            if not done and slot in capped:
-                sched.force_finish(slot, sess.now)
-                done = True
-            events.append(TokenEvent(req.rid, tok, done))
-            if tel.enabled and tel.tracing and slot in first_rows:
-                tel.span(f"{self.name}/req{req.rid}", "prefill",
-                         req.t_admitted, sess.now,
-                         args={"prompt_len": int(sess.plen[slot]),
-                               "prefix_hit_len": req.prefix_hit_len})
-                self._marks[req.rid] = sess.now
-            if done and tel.enabled:
-                self._finish_req(req, sess.now)
+        with tel.section("engine.commit"):
+            capped = set(kv.advance(counts))
+            for slot in list(sched.running):
+                req = sched.running[slot]
+                if kv.lengths[slot] < sess.plen[slot]:
+                    continue             # still mid-prefill: no token yet
+                if slot in first_rows:
+                    sess.count[slot] = 1     # the request's first token
+                else:
+                    sess.count[slot] += 1
+                tok = int(toks[slot])
+                sess.cur[slot] = tok
+                emitted += 1
+                done = sched.on_token(slot, tok, sess.now)  # TTFT on first
+                if not done and slot in capped:
+                    sched.force_finish(slot, sess.now)
+                    done = True
+                events.append(TokenEvent(req.rid, tok, done))
+                if tel.enabled and slot in first_rows:
+                    self._prefilled(req, int(sess.plen[slot]))
+                if done and tel.enabled:
+                    self._finish_req(req)
         if tel.enabled:
+            self._stepped(phase, t_step0, fetch.seconds, n_active)
             tel.engine_tokens.inc(emitted)
             tel.kv_blocks_used.set(kv.used_blocks)
             tel.kv_blocks_free.set(kv.free_blocks)
@@ -856,16 +885,20 @@ class ServingEngine:
         sess.nonfinite += int(bad)
         return np.asarray(toks)
 
-    def _finish_req(self, req: GenRequest, t: float) -> None:
+    def _finish_req(self, req: GenRequest) -> None:
         """Record one request's terminal telemetry (finish counter +
-        closing decode span / finish instant on its trace track)."""
+        closing decode span / finish instant on its trace track), on
+        the host clock."""
         tel = self.telemetry
         tel.sched_finished.labels(reason=req.finish_reason or "done").inc()
+        t = time.perf_counter()
+        t_first = self._marks.pop(req.rid, t)
         if tel.tracing:
             track = f"{self.name}/req{req.rid}"
-            tel.span(track, "decode", self._marks.pop(req.rid, t), t)
+            tel.span(track, "decode", t_first, t, args={"rid": req.rid})
             tel.instant(track, "finish", t,
-                        args={"reason": req.finish_reason,
+                        args={"rid": req.rid,
+                              "reason": req.finish_reason,
                               "out_tokens": len(req.tokens)})
 
     def stream(self, handle: RequestHandle) -> Iterator[int]:

@@ -291,7 +291,7 @@ class ExpertRuntime:
         # shard across updates (the specs are identical for every MoE
         # position, so one jit serves them all).
         self._update_fn = jax.jit(
-            _scatter_slots, donate_argnums=(0,),
+            bank_update, donate_argnums=(0,),
             out_shardings=self._bank_shardings[self.moe_positions[0]])
 
     # ------------------------------------------------------ construction
@@ -403,7 +403,7 @@ class ExpertRuntime:
         warnings.warn(
             f"expert runtime: layer {layer} replica overflowed device {g} "
             f"(cap {sd}/device) and spilled to device {near}",
-            RuntimeWarning, stacklevel=3)
+            RuntimeWarning, stacklevel=4)
         return free_on(near)
 
     # ------------------------------------------------------------ apply
@@ -427,14 +427,19 @@ class ExpertRuntime:
         served plan — consumed only next iteration) accrues
         ``min(cold_start_latency, compute_s)`` of hidden transfer time,
         the analytic bound the measured wall-clock overlap is compared
-        against in serving_bench."""
+        against in serving_bench. Timed on the host clock as the
+        section ``runtime.apply``, with the child ``runtime.flush``."""
         if len(events) != self.n_layers:
             raise ValueError(f"{len(events)} plan events for "
                              f"{self.n_layers} MoE layers")
+        with self.telemetry.section("runtime.apply", phase=phase):
+            return self._apply(t, events, phase, compute_s)
+
+    def _apply(self, t: float, events: list, phase: str,
+               compute_s: float | None) -> ApplyReport:
         rep = ApplyReport()
         rep.rank_bytes = {f"rank{r}": 0.0 for r in range(self.ep)}
         evict0 = self.stats.evictions
-        hidden0 = self.stats.overlap_hidden_s
         updates = {j: ([], [], []) for j in self.moe_positions}
         for layer, ev in enumerate(events):
             self._reap(layer, t)
@@ -509,8 +514,8 @@ class ExpertRuntime:
             self._build_tables(layer, ev.served)
         rep.evictions = self.stats.evictions - evict0
         t_w0 = time.perf_counter()
-        self._flush(updates)
-        flush_wall = time.perf_counter() - t_w0
+        self._flush(updates, rep.transfers)
+        t_w1 = time.perf_counter()
         self._have_tables = True
         self.iterations += 1
         ph = self.stats.phase(phase)
@@ -541,15 +546,10 @@ class ExpertRuntime:
             if rep.exposed:
                 tel.runtime_overlap_copies.labels(kind="exposed").inc(
                     rep.exposed)
-            hid = self.stats.overlap_hidden_s - hidden0
-            if hid:
-                tel.runtime_overlap_hidden.inc(hid)
             tel.runtime_resident.set(self.resident_replicas())
-            tel.runtime_flush_seconds.observe(flush_wall)
+            tel.runtime_flush_seconds.observe(t_w1 - t_w0)
             if tel.tracing and rep.transfers:
-                # span anchored at the serving-clock apply time, with
-                # the flush's measured wall duration
-                tel.span(self.track, "bank_flush", t, t + flush_wall,
+                tel.span(self.track, "bank_flush", t_w0, t_w1,
                          args={"phase": phase,
                                "transfers": rep.transfers,
                                "bytes": rep.bytes_moved})
@@ -571,7 +571,7 @@ class ExpertRuntime:
         a steady stream of small diffs reuses a handful of compiled
         update programs."""
         k = len(ps)
-        bucket = 1 << (k - 1).bit_length()
+        bucket = _bucket(k)
         ps = ps + [ps[-1]] * (bucket - k)
         ss = ss + [ss[-1]] * (bucket - k)
         es = es + [es[-1]] * (bucket - k)
@@ -581,7 +581,7 @@ class ExpertRuntime:
             jnp.asarray(ss, jnp.int32),
             jnp.asarray(es, jnp.int32))
 
-    def _flush(self, updates: dict) -> None:
+    def _flush(self, updates: dict, transfers: int) -> None:
         """Write the changed slots' weights into the device banks.
 
         Double-buffered (default): the new diff PLUS the diff the front
@@ -591,21 +591,33 @@ class ExpertRuntime:
         is reading, so the copies overlap compute instead of serialising
         behind it. ``bytes_moved`` / ``rank_bytes`` meter each replica
         copy once (the logical cold-start traffic); the catch-up write
-        is pipeline bookkeeping, not a second cold start."""
+        is pipeline bookkeeping, not a second cold start. The scatters
+        run inside the section ``runtime.flush``, whose args are the
+        apply's `transfers` and each scatter's power-of-two ``bucket``."""
+        writes = []
         for j, (ps, ss, es) in updates.items():
             if not self.double_buffer:
                 if len(ps):
-                    self.banks[j] = self._scatter(
-                        self.banks[j], j, ps, ss, es)
+                    writes.append((j, ps, ss, es))
                 continue
             pp, sp, ep_ = self._pending[j]
             cps, css, ces = pp + list(ps), sp + list(ss), ep_ + list(es)
-            if not cps:
-                continue
-            back = self._scatter(self._back[j], j, cps, css, ces)
-            self._back[j] = self.banks[j]
-            self.banks[j] = back
-            self._pending[j] = (list(ps), list(ss), list(es))
+            if cps:
+                writes.append((j, cps, css, ces))
+                self._pending[j] = (list(ps), list(ss), list(es))
+        if not writes:
+            return
+        buckets = ",".join(str(_bucket(len(w[1]))) for w in writes)
+        with self.telemetry.section("runtime.flush", transfers=transfers,
+                                    bucket=buckets):
+            for j, ps, ss, es in writes:
+                if not self.double_buffer:
+                    self.banks[j] = self._scatter(
+                        self.banks[j], j, ps, ss, es)
+                    continue
+                back = self._scatter(self._back[j], j, ps, ss, es)
+                self._back[j] = self.banks[j]
+                self.banks[j] = back
 
     # ------------------------------------------------------------ export
 
@@ -646,9 +658,15 @@ class ExpertRuntime:
         return self.stats
 
 
-def _scatter_slots(banks, padded, p_idx, s_idx, e_idx):
+def _bucket(k: int) -> int:
+    """The power-of-two scatter size a diff of `k` slots is padded to."""
+    return 1 << (k - 1).bit_length()
+
+
+def bank_update(banks, padded, p_idx, s_idx, e_idx):
     """banks[k] (P, S, ...), padded[k] (P, E+1, ...): write the (K,)
     changed slots' expert rows. Runs donated under jit — only the
-    touched rows move."""
+    touched rows move. Its device program is ``jit_bank_update``, the
+    name a profiler trace gives each slot-bank write."""
     return {k: b.at[p_idx, s_idx].set(padded[k][p_idx, e_idx])
             for k, b in banks.items()}

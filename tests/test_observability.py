@@ -284,7 +284,10 @@ def test_control_plane_l1_error_hand_computed():
         assert d[f'control_imbalance_factor{{layer="{l}"}}'] == \
             pytest.approx(8.0 / 6.0)
     assert d['control_iterations_total{phase="decode"}'] == 1
-    assert d["control_layer_latency_seconds_count"] == cp.n_layers
+    # one host-timed control step; the modelled per-layer latency family
+    # is gone from the registry
+    assert d["control_step_seconds_count"] == 1
+    assert not any(k.startswith("control_layer_latency") for k in d)
     assert "control_stragglers_total" not in d       # 8/6 < 1.5
 
 
@@ -423,3 +426,264 @@ def test_gateway_scenario_trace_and_metrics(smoke_model, tmp_path):
     # every admitted request got its own queue->prefill->decode->finish
     finishes = [e for e in obj["traceEvents"] if e["name"] == "finish"]
     assert len(finishes) == d["scheduler_admitted_total"]
+
+
+# ------------------------------------------------- host-clock sections
+
+
+def test_noop_section_is_the_shared_noop_context():
+    a = NOOP.section("engine.step", step_num=1)
+    b = NOOP.section("control.plan")
+    assert a is b
+    with a as s:
+        assert s is a
+    assert a.seconds == 0.0
+
+
+def test_sections_nest_on_the_host_clock_with_parent_and_rid():
+    import time
+    tel = Telemetry(tracer=Tracer())
+    t_before = time.perf_counter()
+    with tel.section("engine.step", step_num=0) as outer:
+        with tel.section("engine.dispatch", phase="mixed", rows=2,
+                         prefill_rows=1):
+            pass
+        with tel.section("engine.commit", rid=7):
+            pass
+    t_after = time.perf_counter()
+    evs = tel.tracer.to_obj()["traceEvents"]
+    spans = [e for e in evs if e["ph"] == "X"]
+    # appended as they open: start order, parent before its children
+    assert [e["name"] for e in spans] == \
+        ["engine.step", "engine.dispatch", "engine.commit"]
+    by = {e["name"]: e for e in spans}
+    step = by["engine.step"]
+    assert t_before * 1e6 <= step["ts"]
+    assert step["ts"] + step["dur"] <= t_after * 1e6 + 1e-3
+    assert outer.seconds == pytest.approx(step["dur"] * 1e-6, abs=1e-9)
+    assert "parent" not in step.get("args", {})
+    assert step["args"]["step_num"] == 0
+    for name in ("engine.dispatch", "engine.commit"):
+        c = by[name]
+        assert c["args"]["parent"] == "engine.step"
+        assert c["tid"] == step["tid"]
+        assert step["ts"] <= c["ts"]
+        assert c["ts"] + c["dur"] <= step["ts"] + step["dur"] + 1e-3
+    assert by["engine.dispatch"]["args"]["phase"] == "mixed"
+    assert by["engine.commit"]["args"]["rid"] == 7
+    track = [e for e in evs if e["ph"] == "M"
+             and e["tid"] == step["tid"]][0]["args"]["name"]
+    assert track.startswith("host/")
+
+
+def test_sections_keep_one_parent_stack_per_thread():
+    import threading
+    tel = Telemetry(tracer=Tracer())
+
+    def worker():
+        with tel.section("runtime.apply"):
+            with tel.section("runtime.flush", transfers=2, bucket="2"):
+                pass
+
+    with tel.section("engine.step"):
+        th = threading.Thread(target=worker, name="flusher")
+        th.start()
+        th.join(timeout=30)
+    assert not th.is_alive()
+    by = {e["name"]: e for e in tel.tracer.to_obj()["traceEvents"]
+          if e["ph"] == "X"}
+    assert "parent" not in by["runtime.apply"].get("args", {})
+    assert by["runtime.flush"]["args"]["parent"] == "runtime.apply"
+    assert by["runtime.apply"]["tid"] != by["engine.step"]["tid"]
+    assert by["runtime.flush"]["tid"] == by["runtime.apply"]["tid"]
+
+
+def test_section_lands_in_the_profiler_trace(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    tel = Telemetry()                   # no tracer: profiler spans only
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tel.section("engine.step", step_num=3):
+            with tel.section("engine.dispatch", phase="decode", rows=4,
+                             prefill_rows=0):
+                jax.block_until_ready(jnp.ones(4) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    evs = {e.name: (e, dict(e.stats)) for p in pd.planes
+           if p.name.startswith("/host:") for line in p.lines
+           for e in line.events if e.name.startswith("engine.")}
+    assert set(evs) == {"engine.step", "engine.dispatch"}
+    assert evs["engine.dispatch"][1] == \
+        {"phase": "decode", "rows": 4, "prefill_rows": 0}
+    assert evs["engine.step"][1]["step_num"] == 3
+    step, disp = evs["engine.step"][0], evs["engine.dispatch"][0]
+    assert step.start_ns <= disp.start_ns and disp.end_ns <= step.end_ns
+
+
+def _chunked_runtime_engine(cfg, params, tel):
+    from repro.configs import ServingSpec
+    from repro.core.control import ControlPlane
+    from repro.serving.engine import ServingEngine
+    eng = ServingEngine(cfg, params, max_len=32, expert_runtime="on",
+                        telemetry=tel,
+                        serving=ServingSpec(kv="paged", kv_block=4,
+                                            prefill_chunk=4))
+    ctl = ControlPlane(cfg, "moeless", num_devices=8,
+                       max_replicas_per_device=2, telemetry=tel)
+    return eng, ctl
+
+
+def _serve_chunked(cfg, params, tel, time_scale=1.0):
+    eng, ctl = _chunked_runtime_engine(cfg, params, tel)
+    reqs = _mk_reqs(cfg, 3, gen=4, prompt_len=8)
+    for r in reqs:
+        r.arrival = 0.0
+    res = eng.serve(reqs, num_slots=2, control=ctl, time_scale=time_scale)
+    return reqs, res
+
+
+def test_instrumented_chunked_runtime_serve_identical_to_noop(smoke_model):
+    """Sections, host-clock spans and the runtime's flush sections are
+    observation-only on the chunked-prefill path with the expert
+    runtime executing plans: tokens and modelled metrics match NOOP."""
+    cfg, params = smoke_model
+
+    def run(tel):
+        reqs, res = _serve_chunked(cfg, params, tel)
+        return ([(r.rid, tuple(r.tokens)) for r in reqs],
+                [(r.rid, r.ttft, r.e2e) for r in res.records],
+                res.iterations, res.runtime.stats.transfers)
+
+    assert run(None) == run(Telemetry(tracer=Tracer()))
+
+
+def test_engine_spans_run_on_the_host_clock(smoke_model):
+    """With the modelled clock scaled far past wall time, every span of
+    a traced serve still lies inside the serve's host interval, and the
+    sections nest as engine.step > engine.* / control.step / runtime.apply
+    > control.loads, control.plan / runtime.flush."""
+    import time
+    cfg, params = smoke_model
+    tel = Telemetry(tracer=Tracer())
+    t0 = time.perf_counter()
+    reqs, res = _serve_chunked(cfg, params, tel, time_scale=1e8)
+    t1 = time.perf_counter()
+    assert res.clock_s > 10 * (t1 - t0)        # the modelled clock ran off
+    evs = [e for e in tel.tracer.to_obj()["traceEvents"] if e["ph"] != "M"]
+    for e in evs:
+        assert t0 * 1e6 <= e["ts"] <= t1 * 1e6, e
+        assert e["ts"] + e.get("dur", 0.0) <= t1 * 1e6 + 1e-3, e
+    want_parent = {
+        "engine.admit": "engine.step", "engine.prepare": "engine.step",
+        "engine.dispatch": "engine.step", "engine.fetch": "engine.step",
+        "engine.commit": "engine.step", "control.step": "engine.step",
+        "runtime.apply": "engine.step", "control.loads": "control.step",
+        "control.plan": "control.step", "runtime.flush": "runtime.apply"}
+    seen = set()
+    for e in evs:
+        if e["name"] == "runtime.apply" \
+                and e["args"]["phase"] == "bootstrap":
+            assert "parent" not in e["args"]    # applied at start()
+        elif e["name"] in want_parent:
+            assert e["args"]["parent"] == want_parent[e["name"]], e
+            seen.add(e["name"])
+    assert seen == set(want_parent)
+    steps = [e for e in evs if e["name"] == "engine.step"]
+    assert len(steps) == res.iterations
+    dispatch = [e["args"] for e in evs if e["name"] == "engine.dispatch"]
+    phases = {a["phase"] for a in dispatch}
+    assert phases == {"mixed", "decode"}
+    assert all((a["prefill_rows"] > 0) == (a["phase"] == "mixed")
+               and a["rows"] >= a["prefill_rows"] for a in dispatch)
+    flush = [e["args"] for e in evs if e["name"] == "runtime.flush"]
+    for a in flush:
+        for b in str(a["bucket"]).split(","):
+            assert int(b) & (int(b) - 1) == 0          # a power of two
+    # each request's spans share its rid and run queue -> prefill ->
+    # decode -> finish in host time
+    for r in reqs:
+        mine = [e for e in evs if e.get("args", {}).get("rid") == r.rid
+                and e["name"] in ("queue", "prefill", "decode", "finish")]
+        assert [e["name"] for e in mine] == \
+            ["queue", "prefill", "decode", "finish"]
+        for a, b in zip(mine, mine[1:]):
+            assert a["ts"] + a.get("dur", 0.0) <= b["ts"] + 1e-3
+
+
+def test_step_and_queue_histograms_on_the_host_clock(smoke_model):
+    import time
+    cfg, params = smoke_model
+    tel = Telemetry()
+    t0 = time.perf_counter()
+    _, res = _serve_chunked(cfg, params, tel, time_scale=1e8)
+    wall = time.perf_counter() - t0
+    d = tel.registry.as_dict()
+    n = 0
+    for phase in ("mixed", "decode"):
+        k = d[f'engine_steps_total{{phase="{phase}"}}']
+        assert d[f'engine_step_seconds_count{{phase="{phase}"}}'] == k
+        n += k
+    assert n == res.iterations
+    step_s = sum(d[f'engine_step_seconds_sum{{phase="{p}"}}']
+                 for p in ("mixed", "decode"))
+    assert 0.0 < step_s <= wall
+    assert d["scheduler_queue_delay_seconds_count"] == 3
+    assert 0.0 <= d["scheduler_queue_delay_seconds_sum"] <= 3 * wall
+    assert d["control_step_seconds_count"] == res.iterations
+    assert 0.0 < d["control_step_seconds_sum"] <= step_s
+    assert d["runtime_bank_flush_seconds_count"] >= res.iterations
+    assert "runtime_overlap_hidden_seconds_total" not in d
+    text = tel.registry.render_prometheus()
+    assert "runtime_overlap_hidden" not in text
+    assert "control_layer_latency" not in text
+    assert "# HELP runtime_bank_flush_seconds host wall time to dispatch" \
+        in text
+
+
+def test_programs_carry_stable_names(smoke_model, tmp_path):
+    """The engine's step and the runtime's slot-bank update run as jitted
+    programs named engine_step and bank_update (XLA modules jit_<name>
+    on the chip), and the greedy sampler as JAX's own _argmax, seen here
+    as the profiler's host events for their calls, each inside its
+    section."""
+    import jax
+    from jax.profiler import ProfileData
+    cfg, params = smoke_model
+    tel = Telemetry()
+    eng, ctl = _chunked_runtime_engine(cfg, params, tel)
+    reqs = _mk_reqs(cfg, 2, gen=3, prompt_len=8)
+    eng.start(num_slots=2, control=ctl)
+    for r in reqs:
+        eng.submit(r)
+    eng.step()                          # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        while eng.has_work:
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    eng.close()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    host = [(e.name, e.start_ns, e.end_ns) for p in pd.planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events]
+
+    def inside(prog, section):
+        calls = [(s, e) for n, s, e in host
+                 if n == f"PjitFunction({prog})"]
+        spans = [(s, e) for n, s, e in host if n == section]
+        assert calls and spans, (prog, section)
+        return all(any(a <= s and e <= b for a, b in spans)
+                   for s, e in calls)
+
+    assert inside("engine_step", "engine.dispatch")
+    assert inside("_argmax", "engine.fetch")
+    assert inside("bank_update", "runtime.flush")

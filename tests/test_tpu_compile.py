@@ -9,6 +9,7 @@ fixture (never at import time), so every test worker collects the same
 tests and only the worker that runs this file loads the TPU library.
 """
 import os
+import re
 from functools import partial
 
 import jax
@@ -59,12 +60,19 @@ def _compiled_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel(text):
+    """The kernel names the compiled program's Pallas calls carry in
+    their ``kernel_metadata``, the handle a profiler trace shows."""
+    return set(re.findall(r'"kernel":"(\w+)"', text))
+
+
 @pytest.mark.parametrize("cap", CAPACITIES)
 def test_gmm_compiles(one_chip, cap):
     s = partial(_spec, one_chip)
     text = _compiled_text(moe_gmm.gmm, s((E, cap, F), jnp.bfloat16),
                           s((E, F, D), jnp.bfloat16), s((E,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _kernel(text) == {"moe_gmm"}
 
 
 @pytest.mark.parametrize("cap", CAPACITIES)
@@ -75,6 +83,7 @@ def test_fused_gate_up_compiles(one_chip, cap):
                           s((E, D, F), jnp.bfloat16),
                           s((E, D, F), jnp.bfloat16), s((E,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _kernel(text) == {"moe_ffn"}
 
 
 @pytest.mark.parametrize("cap", CAPACITIES)
@@ -86,6 +95,7 @@ def test_gmm_quant_compiles(one_chip, cap):
                           s((SLOTS, F), jnp.float32),
                           s((SLOTS,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _kernel(text) == {"moe_gmm_quant"}
 
 
 @pytest.mark.parametrize("cap", CAPACITIES)
@@ -99,6 +109,7 @@ def test_fused_gate_up_quant_compiles(one_chip, cap):
                           s((SLOTS, D), jnp.float32),
                           s((SLOTS,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _kernel(text) == {"moe_ffn_quant"}
 
 
 @pytest.mark.parametrize("seq", (320, 1280))
@@ -111,6 +122,7 @@ def test_decode_attention_compiles(one_chip, seq):
                           s((BATCH, seq), jnp.int32),
                           s((BATCH,), jnp.int32), s((BATCH,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _kernel(text) == {"decode_attn"}
 
 
 def test_decode_attention_paged_compiles(one_chip):
@@ -125,6 +137,7 @@ def test_decode_attention_paged_compiles(one_chip):
                           s((BATCH, nbs), jnp.int32),
                           s((BATCH,), jnp.int32), s((BATCH,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _kernel(text) == {"decode_attn_paged"}
 
 
 def test_mixtral_decode_step_compiles(one_chip):
@@ -153,6 +166,7 @@ def test_mixtral_decode_step_compiles(one_chip):
                                    s((BATCH,), jnp.int32)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3   # attention + 2 GMMs
+    assert {"decode_attn_paged", "moe_ffn"} <= _kernel(text)
     mem = compiled.memory_analysis()
     # one bf16 layer plus embeddings must fit the chip's 16 GiB
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
