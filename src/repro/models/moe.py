@@ -69,8 +69,8 @@ def load_balance_loss(probs, top_i, num_experts: int):
 def experts_ffn(p, x, act: str, *, group_sizes=None, impl: str = "ref"):
     """x: (E, N, D) -> (E, N, D), grouped per-expert FFN through the
     kernels.ops backend selector. `group_sizes` (E,) marks rows beyond
-    it as padding (outputs zeroed; the Pallas backends also skip whole
-    row-tiles there). None => all rows active.
+    it as padding (outputs zeroed; the Pallas backends neither copy nor
+    compute the row tiles there). None => all rows active.
 
     `p` may be a native-dtype bank ({w_gate, w_up, w_down}) or an int8
     quantized slot bank carrying `*_scale` companions

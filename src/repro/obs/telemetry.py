@@ -22,13 +22,15 @@ instrumentation site and nothing else. Instrument sites guard with
 Metric naming follows Prometheus conventions —
 ``<subsystem>_<name>_<unit>[_total]`` with the subsystem one of
 ``scheduler`` / ``engine`` / ``runtime`` / ``control`` / ``router``
-(+ per-replica ``replica_*`` gauges). The README's Observability
+(+ per-replica ``replica_*`` gauges, and the ``kernel_grid_steps``
+gauge of the GMM kernels' static schedule). The README's Observability
 section tables the full taxonomy.
 """
 from __future__ import annotations
 
 import threading
 import time
+import weakref
 
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
@@ -186,6 +188,20 @@ class Telemetry:
         self.replica_healthy = r.gauge(
             "replica_healthy", "1 while the replica serves",
             labels=("replica",))
+        # ------------------------------------------------------ kernels
+        self.kernel_grid_steps = r.gauge(
+            "kernel_grid_steps",
+            "grid steps of one call of a grouped-GMM kernel, by kernel "
+            "and capacity (static, recorded when the kernel is traced)",
+            labels=("kernel", "capacity"))
+        for (kernel, capacity), steps in _KERNEL_SCHEDULES.items():
+            self._set_schedule(kernel, capacity, steps)
+        _LIVE.add(self)
+
+    def _set_schedule(self, kernel: str, capacity: int,
+                      grid_steps: int) -> None:
+        self.kernel_grid_steps.labels(
+            kernel=kernel, capacity=capacity).set(grid_steps)
 
     # ------------------------------------------------------- tracing
 
@@ -219,6 +235,23 @@ class Telemetry:
                 args: dict | None = None) -> None:
         if self.tracer is not None:
             self.tracer.instant(track, name, t, args)
+
+
+# Kernel schedules are facts of the process's traced programs, not of
+# one engine: each is kept here and set on every live `Telemetry`.
+_KERNEL_SCHEDULES: dict[tuple[str, int], int] = {}
+_LIVE: "weakref.WeakSet[Telemetry]" = weakref.WeakSet()
+
+
+def record_kernel_schedule(kernel: str, capacity: int,
+                           grid_steps: int) -> None:
+    """Record the static schedule of a grouped-GMM kernel as it is
+    traced: its grid steps per call at `capacity` rows per slot, in the
+    ``kernel_grid_steps`` gauge of every `Telemetry` alive now or made
+    later. Costs nothing per step."""
+    _KERNEL_SCHEDULES[(kernel, capacity)] = grid_steps
+    for tel in list(_LIVE):
+        tel._set_schedule(kernel, capacity, grid_steps)
 
 
 class _Section:

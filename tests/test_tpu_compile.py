@@ -28,9 +28,10 @@ H, KV, HD = 32, 8, 128
 SLOTS = 20          # runtime slot bank: 4 logical devices x 5 slots
 BATCH = 8
 KV_BLOCK = 16
-# capacity rows per expert: decode (8 tokens, cf 1.25), a 64-token
-# prefill chunk over 8 rows, and a drop-free cf=8 chunk
-CAPACITIES = (3, 160, 1024)
+# capacity rows per expert: decode (8 tokens, cf 1.25), the benchmark
+# cell's decode step (8 rows, cf 8), a 64-token prefill chunk over 8
+# rows, and a drop-free cf=8 chunk (the cell's mixed step)
+CAPACITIES = (3, 16, 160, 1024)
 
 
 @pytest.fixture(scope="module")
@@ -66,6 +67,21 @@ def _kernel(text):
     return set(re.findall(r'"kernel":"(\w+)"', text))
 
 
+def _result_ranks(text, kernel):
+    """Ranks of the arrays the custom calls named `kernel` (the op name
+    a trace shows, ``<kernel>.<n>``) return: a trace reader tells the
+    grouped GMMs (rank 3) from decode attention by it."""
+    ranks = []
+    for line in text.splitlines():
+        lhs, _, rhs = line.partition(" = ")
+        op = lhs.split()[-1].lstrip("%").split(".")[0] if lhs else ""
+        if " custom-call(" in rhs and op == kernel:
+            shape = rhs.split(" ", 1)[0]
+            ranks.append(len(shape[shape.index("[") + 1:
+                                   shape.index("]")].split(",")))
+    return ranks
+
+
 @pytest.mark.parametrize("cap", CAPACITIES)
 def test_gmm_compiles(one_chip, cap):
     s = partial(_spec, one_chip)
@@ -96,6 +112,7 @@ def test_gmm_quant_compiles(one_chip, cap):
                           s((SLOTS,), jnp.int32))
     assert "tpu_custom_call" in text
     assert _kernel(text) == {"moe_gmm_quant"}
+    assert _result_ranks(text, "moe_gmm_quant") == [3]
 
 
 @pytest.mark.parametrize("cap", CAPACITIES)
@@ -110,6 +127,7 @@ def test_fused_gate_up_quant_compiles(one_chip, cap):
                           s((SLOTS,), jnp.int32))
     assert "tpu_custom_call" in text
     assert _kernel(text) == {"moe_ffn_quant"}
+    assert _result_ranks(text, "moe_ffn_quant") == [3]
 
 
 @pytest.mark.parametrize("seq", (320, 1280))
