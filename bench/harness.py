@@ -30,7 +30,7 @@ import shutil
 import sys
 import time
 import warnings
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from pathlib import Path
 
 import numpy as np
@@ -95,29 +95,52 @@ def load_reader(name: str, bench_dir: Path = BENCH):
 
 def program_config(c: dict):
     """The program's ModelConfig for a configuration file (its layout
-    checked by `bench.weights.dims`)."""
-    from bench.weights import dims
-    dims(c)
+    checked by `bench.weights.dims`).
+
+    A layout key at Mixtral's value (`bench.weights.LAYOUT`) is not
+    passed on. One at another value goes to the program's field of the
+    same name (`router` to `MoESpec`, the rest to `ModelConfig`), frozen
+    as `bench.weights.frozen` gives it; `norm` has its field already. A
+    program that declares no such field does not serve the layout, and
+    the file is refused naming each key it lacks, so a program gains a
+    layout by declaring the field, with no change here."""
+    from bench.weights import LAYOUT, dims, frozen
+    m = dims(c)
     from repro.configs.base import ModelConfig, MoESpec, ServingSpec
     s = c["serving"]
-    return ModelConfig(
+    moe = dict(num_experts=c["num_local_experts"],
+               top_k=c["num_experts_per_tok"],
+               d_ff=c["intermediate_size"],
+               capacity_factor=s["capacity_factor"],
+               router_jitter=float(c.get("router_jitter_noise", 0.0)),
+               slot_dtype=s["slot_dtype"])
+    top = dict(
         name=c["model_type"], family="moe",
         num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
         num_heads=c["num_attention_heads"],
         num_kv_heads=c["num_key_value_heads"],
         d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        head_dim=c["hidden_size"] // c["num_attention_heads"],
-        moe=MoESpec(num_experts=c["num_local_experts"],
-                    top_k=c["num_experts_per_tok"],
-                    d_ff=c["intermediate_size"],
-                    capacity_factor=s["capacity_factor"],
-                    slot_dtype=s["slot_dtype"]),
+        head_dim=m["hd"],
         serving=ServingSpec(kv="paged", kv_block=s["kv_block"],
                             prefill_chunk=s["prefill_chunk"],
                             prefix_cache=s["prefix_cache"]),
         rope="rope", rope_theta=float(c["rope_theta"]),
-        norm=c["norm"], qkv_bias=False, act="swiglu",
+        norm=m["norm"], qkv_bias=False, act="swiglu",
         tie_embeddings=False, dtype="bfloat16")
+    lacking = []
+    for key, default in LAYOUT.items():
+        value = c.get(key, default)
+        if key == "norm" or value == default:
+            continue
+        kw, cls = (moe, MoESpec) if key == "router" else (top, ModelConfig)
+        if key in {f.name for f in fields(cls)}:
+            kw[key] = frozen(value)
+        else:
+            lacking.append(f"{key} {value!r}")
+    if lacking:
+        raise ValueError("unsupported layout: the program serves no "
+                         + "; no ".join(lacking))
+    return ModelConfig(moe=MoESpec(**moe), **top)
 
 
 # ------------------------------------------------------------ records
@@ -453,25 +476,33 @@ def check_sample(reqs: list, seed: int) -> list:
 
 
 def compare(c: dict, seed: int, sample: list,
-            lower_pick: bool = False) -> np.ndarray:
+            lower_pick: bool = False) -> tuple:
     """Gap, under the float32 reference, between the best logit and the
-    logit of each served token of the sample, all tokens in one array.
-    With `lower_pick`, of the token the lower-precision control puts
-    first at each of those positions instead (the control's reading)."""
+    logit of each served token of the sample, all tokens in one array,
+    and the reference's router margin at each. With `lower_pick`, the
+    gap of the token the lower-precision control puts first at each of
+    those positions instead (the control's reading)."""
     from bench.reference import Reference, served_gaps
     ref = Reference(c, seed)
-    return np.concatenate([served_gaps(ref, r.prompt, r.tokens,
-                                       lower_pick=lower_pick)
-                           for r in sample])
+    got = [served_gaps(ref, r.prompt, r.tokens, lower_pick=lower_pick)
+           for r in sample]
+    return tuple(np.concatenate(x) for x in zip(*got))
 
 
-def gap_numbers(gaps: np.ndarray) -> dict:
+def gap_numbers(gaps: np.ndarray, margins: np.ndarray,
+                width: float = 0.0) -> dict:
     """The numbers a configuration's `check` may hold a limit for: the
-    widest gap, and the mean gap over the served tokens."""
-    if gaps.size == 0:
-        return {"max_logit_gap": math.inf, "mean_logit_gap": math.inf}
-    return {"max_logit_gap": float(gaps.max()),
-            "mean_logit_gap": float(gaps.mean())}
+    widest gap and the mean gap over the served tokens, and both over
+    the clear tokens, those whose reference router margin is at least
+    `width` (the file's `router_margin`, logits; all tokens at 0): where
+    a near-tie in the router cannot have flipped the served token."""
+    numbers = {}
+    for name, g in (("", gaps), ("clear_", gaps[margins >= width])):
+        numbers[f"max_{name}logit_gap"] = float(g.max()) if g.size \
+            else math.inf
+        numbers[f"mean_{name}logit_gap"] = float(g.mean()) if g.size \
+            else math.inf
+    return numbers
 
 
 # ---------------------------------------------------------------- run
@@ -651,13 +682,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     engine.close()
     del engine, server, ctl, params, tel
     gc.collect()
-    gaps = gap_numbers(compare(c, seed, sample) if sample
-                       else np.zeros(0))
-    control_gaps = gap_numbers(compare(c, seed, sample, lower_pick=True)
-                               if sample else np.zeros(0)) \
-        if control else None
+    width = float(c.get("router_margin", 0.0))
+    none = (np.zeros(0), np.zeros(0))
+    token_gaps, margins = compare(c, seed, sample) if sample else none
+    gaps = gap_numbers(token_gaps, margins, width)
+    control_gaps = gap_numbers(
+        *(compare(c, seed, sample, lower_pick=True) if sample else none),
+        width) if control else None
+    clear = float(np.mean(margins >= width)) if margins.size else 0.0
     log(f"widest gap {gaps['max_logit_gap']!r}, mean gap "
-        f"{gaps['mean_logit_gap']!r} over the sample's served tokens")
+        f"{gaps['mean_logit_gap']!r} over the sample's served tokens; "
+        f"{clear!r} of them clear of the router by {width!r} logits: "
+        f"widest {gaps['max_clear_logit_gap']!r}, "
+        f"mean {gaps['mean_clear_logit_gap']!r}")
     exact = {
         "unfinished_requests": (unfinished, 0),
         "wrong_token_counts": (wrong_len, 0),

@@ -1,6 +1,7 @@
 """Whole runs of a cell at a size the CPU runs, with the harness's look
 for a chip skipped: a sound run is correct; a run whose timed path is
 broken underneath is not; a metric or a configuration is a file."""
+import dataclasses
 import json
 import os
 import subprocess
@@ -8,12 +9,14 @@ import sys
 import time
 
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from bench import harness as H
-from bench.tests.tiny_root import CELL, make_root
+from bench.tests.tiny_root import CELL, DATA, make_root
 
 SEED = 2**31 + 977
+PHI = json.loads((DATA / "tiny-phimoe.json").read_text())
 
 
 def _run(root, trace=False, seconds=3.0, **kw):
@@ -39,15 +42,93 @@ def test_sound_run_is_correct_and_reports_every_metric(tmp_path):
     assert list(res)[-1] == "checks"
 
 
-@pytest.mark.parametrize("change", [{"norm": "layernorm"},
-                                    {"attention_bias": True}],
-                         ids=["layernorm", "attention_bias"])
-def test_layout_the_reference_lacks_is_refused(tmp_path, change):
-    root = make_root(tmp_path)
+def _with(root, change: dict):
     path = root / "bench" / "configs" / "tiny.json"
     path.write_text(json.dumps({**json.loads(path.read_text()), **change}))
+
+
+@pytest.mark.parametrize("change", [
+    {"norm": "batchnorm"}, {"router": "expert_choice"},
+    {"rope_scaling": {**PHI["rope_scaling"], "type": "yarn"}}],
+    ids=["norm", "router", "rope_scaling"])
+def test_layout_the_reference_lacks_is_refused(tmp_path, change):
+    root = make_root(tmp_path)
+    _with(root, change)
     with pytest.raises(ValueError, match="unsupported layout"):
         _run(root)
+
+
+def test_layernorm_run_is_correct(tmp_path):
+    """LayerNorm with its biases, at the program's LayerNorm epsilon."""
+    root = make_root(tmp_path)
+    _with(root, {"norm": "layernorm", "rms_norm_eps": 1e-5})
+    res = _run(root)
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] == 12
+
+
+LAYOUT_KEYS = ("attention_bias", "lm_head_bias", "router", "rope_scaling")
+
+
+@pytest.mark.parametrize("key", LAYOUT_KEYS)
+def test_layout_the_program_lacks_is_refused(key):
+    """A layout key reaches the program's field of its name; where the
+    program declares none, the file is refused naming the key."""
+    from repro.configs.base import ModelConfig, MoESpec
+    c = json.loads((DATA / "tiny.json").read_text())
+    c[key] = PHI[key]
+    if key == "router":
+        c["router_jitter_noise"] = PHI["router_jitter_noise"]
+    cls = MoESpec if key == "router" else ModelConfig
+    if key not in {f.name for f in dataclasses.fields(cls)}:
+        with pytest.raises(ValueError, match=f"unsupported layout.*{key}"):
+            H.program_config(c)
+        return
+    from bench.weights import frozen
+    cfg = H.program_config(c)
+    assert getattr(cfg.moe if key == "router" else cfg, key) \
+        == frozen(PHI[key])
+
+
+def test_phi_layout_refused_naming_each_key_the_program_lacks():
+    from repro.configs.base import ModelConfig, MoESpec
+    declared = {f.name for f in dataclasses.fields(ModelConfig)} \
+        | {f.name for f in dataclasses.fields(MoESpec)}
+    lacking = [k for k in LAYOUT_KEYS if k not in declared]
+    if not lacking:
+        assert H.program_config(PHI).norm == "layernorm"
+        return
+    with pytest.raises(ValueError) as err:
+        H.program_config(PHI)
+    assert all(f"no {k} " in str(err.value) for k in lacking)
+
+
+@pytest.mark.parametrize("name", ["tiny.json", "mixtral-8x7b.rt1.json"])
+def test_mixtral_layout_program_config_unchanged(name):
+    from repro.configs.base import ModelConfig, MoESpec, ServingSpec
+    pins = json.loads((DATA / "mixtral-layout-pins.json").read_text())
+    want = dict(pins["model_configs"][name])
+    want["moe"] = MoESpec(**want["moe"])
+    want["serving"] = ServingSpec(**want["serving"])
+    path = DATA / name if name == "tiny.json" \
+        else H.BENCH / "configs" / name
+    assert H.program_config(json.loads(path.read_text())) \
+        == ModelConfig(**want)
+
+
+def test_clear_gaps():
+    rng = np.random.default_rng(3)
+    gaps, margins = rng.random(200), rng.random(200)
+    g = H.gap_numbers(gaps, margins)
+    assert g["mean_clear_logit_gap"] == g["mean_logit_gap"]
+    assert g["max_clear_logit_gap"] == g["max_logit_gap"]
+    g = H.gap_numbers(gaps, margins, 0.5)
+    clear = gaps[margins >= 0.5]
+    assert g["mean_clear_logit_gap"] == float(clear.mean())
+    assert g["max_clear_logit_gap"] == float(clear.max())
+    assert g["mean_logit_gap"] == float(gaps.mean())
+    assert H.gap_numbers(gaps, margins, 2.0)["mean_clear_logit_gap"] \
+        == float("inf")
 
 
 def test_closed_loop_keeps_its_clients_busy(tmp_path):
