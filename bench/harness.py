@@ -93,6 +93,10 @@ def load_reader(name: str, bench_dir: Path = BENCH):
     return mod.read
 
 
+# the file keys that `program_config` passes to `MoESpec`, not `ModelConfig`
+MOE_KEYS = ("router", "num_local_experts", "first_local_expert")
+
+
 def program_config(c: dict):
     """The program's ModelConfig for a configuration file (its layout
     checked by `bench.weights.dims`).
@@ -103,12 +107,18 @@ def program_config(c: dict):
     as `bench.weights.frozen` gives it; `norm` has its field already. A
     program that declares no such field does not serve the layout, and
     the file is refused naming each key it lacks, so a program gains a
-    layout by declaring the field, with no change here."""
-    from bench.weights import LAYOUT, dims, frozen
+    layout by declaring the field, with no change here.
+
+    `MoESpec.num_experts` is the router's width (`n_routed_experts`). A
+    file that holds a share of each expert layer (`bench.weights`
+    `is_share`) passes `num_local_experts` and `first_local_expert` to
+    the `MoESpec` fields of those names, refused alike where they lack."""
+    from bench.weights import LAYOUT, dims, experts_of, frozen, is_share
     m = dims(c)
     from repro.configs.base import ModelConfig, MoESpec, ServingSpec
     s = c["serving"]
-    moe = dict(num_experts=c["num_local_experts"],
+    routed, held, first = experts_of(c)
+    moe = dict(num_experts=routed,
                top_k=c["num_experts_per_tok"],
                d_ff=c["intermediate_size"],
                capacity_factor=s["capacity_factor"],
@@ -127,14 +137,16 @@ def program_config(c: dict):
         rope="rope", rope_theta=float(c["rope_theta"]),
         norm=m["norm"], qkv_bias=False, act="swiglu",
         tie_embeddings=False, dtype="bfloat16")
+    passed = [(key, frozen(c.get(key, default)))
+              for key, default in LAYOUT.items()
+              if key != "norm" and c.get(key, default) != default]
+    if is_share(c):
+        passed += [("num_local_experts", held), ("first_local_expert", first)]
     lacking = []
-    for key, default in LAYOUT.items():
-        value = c.get(key, default)
-        if key == "norm" or value == default:
-            continue
-        kw, cls = (moe, MoESpec) if key == "router" else (top, ModelConfig)
+    for key, value in passed:
+        kw, cls = (moe, MoESpec) if key in MOE_KEYS else (top, ModelConfig)
         if key in {f.name for f in fields(cls)}:
-            kw[key] = frozen(value)
+            kw[key] = value
         else:
             lacking.append(f"{key} {value!r}")
     if lacking:
@@ -578,7 +590,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     cfg = program_config(c)
     params = make_weights(c, seed)
     topics = None
-    if mix["ids"]["kind"] == "expert_topics":
+    if mix["ids"]["kind"] == "expert_topics":    # over the routed width
         topics = TR.topic_sets(token_top1(c, params), cfg.moe.num_experts)
     gen = TR.Generator(mix, seed, cfg.vocab_size, topics)
     wgen = TR.Generator(mix, seed + 1, cfg.vocab_size, topics)
@@ -594,8 +606,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                            max_len=TR.max_len(mix, s["kv_block"]),
                            expert_runtime=s.get("expert_runtime", "on"),
                            mesh=mesh, telemetry=tel)
+    # the engine holds the weights now; what stays resident is its choice
+    del params
     ctl = ControlProxy(MoElessController(
-        cfg, num_devices=s["control_devices"], telemetry=tel))
+        cfg, num_devices=s["control_devices"],
+        slots_per_device=s.get("slots_per_device", 0), telemetry=tel))
     engine.start(num_slots=s["num_slots"], control=ctl)
     server = Server(engine, tel, ctl, s["prefill_chunk"])
     warm_up(server, burst, stretch, mix, mix["warmup_seconds"], meter)
@@ -680,7 +695,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     # free the program's state, then check against the reference
     sample = check_sample(reqs, seed)
     engine.close()
-    del engine, server, ctl, params, tel
+    del engine, server, ctl, tel
     gc.collect()
     width = float(c.get("router_margin", 0.0))
     none = (np.zeros(0), np.zeros(0))

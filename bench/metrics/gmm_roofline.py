@@ -1,10 +1,11 @@
 """Share of its roofline that the dequantising grouped-GMM kernels
 reach: the least time the chip needs for the window's routed expert work
-(`bench/flops.py` `gmm_work`, from the routed loads of every step),
+(`bench/flops.py` `gmm_work`, from the routed loads of every step, those
+of the held experts where the file holds a share),
 over the device time of the GMM kernels' events in the trace. The
 routed work is bound by memory in decode steps and near the balance
 point in mixed steps; each step takes the larger of its two times."""
-from bench.flops import gmm_work, roofline_s
+from bench.flops import gmm_work, held_loads, roofline_s
 from bench.trace_reduce import kernel_rank
 from bench.weights import dims
 
@@ -25,7 +26,7 @@ def read(run):
     for s in run.steps:
         if s.loads is None:
             continue
-        for layer in s.loads:
+        for layer in held_loads(m, s.loads):
             need += roofline_s(*gmm_work(layer, m["d"], m["f"]),
                                run.peaks)[0]
     return 100.0 * need / spent if need > 0 else None

@@ -10,6 +10,7 @@ def read(run):
     if not run.steps:
         return None
     m = dims(run.config)
-    flops = sum(step_flops(m, s.prefill, s.decode_ctx) for s in run.steps)
+    flops = sum(step_flops(m, s.prefill, s.decode_ctx, s.loads)
+                for s in run.steps)
     secs = sum(s.t1 - s.t0 for s in run.steps)
     return 100.0 * flops / secs / run.peaks["bf16_flops_per_s"]

@@ -29,6 +29,13 @@ experts, final norm and untied head. The file's layout keys
   reference refuses such a sequence instead (the benchmark's traffic
   stays under it).
 
+A file that holds a share of each expert layer (`num_local_experts`
+of `n_routed_experts`, from `first_local_expert`; `bench.weights`)
+routes over every routed expert: gates and router margins come from the
+whole router. Only the held experts' gated FFNs are summed; an expert
+held elsewhere adds nothing, and that partial sum goes on to the next
+layer, as the chip that holds this share computes it.
+
 Departures of the served program from the published models are stated
 in each configuration file and `PERF.md`.
 
@@ -181,9 +188,12 @@ def _attention(q, k, v, m):
 
 
 def _experts(h, ex, gate, m, lower: bool):
-    """sum_e gate[:, e] * FFN_e(h) over every expert (dense). Int8
-    experts take their int8 values (int4 in the control); bfloat16
-    experts their own values (float8 in the control)."""
+    """sum_e gate[:, e] * FFN_e(h) over every held expert (dense); `gate`
+    spans the routed experts. Int8 experts take their int8 values (int4
+    in the control); bfloat16 experts their own values (float8 in the
+    control)."""
+    if m["e"] < m["routed"]:
+        gate = gate[:, m["first"]:m["first"] + m["e"]]
     levels = 7 if lower else 127
 
     def weight(w):
@@ -218,9 +228,19 @@ def _route(rl, m):
         return _sparsemixer(rl, m["jitter"])
     top_v, top_i = jax.lax.top_k(rl, m["k"])
     top_w = jax.nn.softmax(top_v, axis=-1)
-    gate = jnp.zeros((n, m["e"]), jnp.float32).at[
+    gate = jnp.zeros((n, m["routed"]), jnp.float32).at[
         jnp.arange(n)[:, None], top_i].add(top_w)
     return gate, _margin_topk(rl, m["k"])
+
+
+def _moe(hn, p, m, lower: bool, router: str):
+    """One MoE sublayer on its normed input: the held experts' part of
+    the output and the router margin."""
+    rl = _mm(hn, p["router"]["w_gate"], lower)
+    if router == "bf16":
+        rl = rl.astype(jnp.bfloat16).astype(jnp.float32)
+    gate, margin = _route(rl, m)
+    return _experts(hn, p["experts"], gate, m, lower), margin
 
 
 @partial(jax.jit, static_argnums=(0, 3, 4))
@@ -242,13 +262,9 @@ def _forward(spec: tuple, w, tokens, lower: bool, router: str):
         k = _rope(k.reshape(n, m["kv"], m["hd"]), pos, theta, rs)
         v = v.reshape(n, m["kv"], m["hd"])
         x = x + _bias(_mm(_attention(q, k, v, m), a["wo"], lower), a, "bo")
-        hn = _norm(x, p["norm2"], m)
-        rl = _mm(hn, p["moe"]["router"]["w_gate"], lower)
-        if router == "bf16":
-            rl = rl.astype(jnp.bfloat16).astype(jnp.float32)
-        gate, mg = _route(rl, m)
+        y, mg = _moe(_norm(x, p["norm2"], m), p["moe"], m, lower, router)
         margin = jnp.minimum(margin, mg)
-        x = x + _experts(hn, p["moe"]["experts"], gate, m, lower)
+        x = x + y
     hn = _norm(x, w["final_norm"], m)
     logits = _bias(_mm(hn, w["head"], lower), w, "head_bias")
     return logits[:, :m["vocab"]], margin
@@ -274,9 +290,10 @@ class Reference:
         self.spec = spec_of(c)
         self.w = make_weights(c, seed)
 
-    def forward(self, tokens, lower: bool = False, router: str = "f32"):
-        """(len(tokens), vocab) float32 logits on the device, position i
-        predicting token i + 1, and (len(tokens),) router margins."""
+    def padded(self, tokens, lower: bool = False, router: str = "f32"):
+        """`forward` at the length padded to a multiple of BUCKET, whose
+        programs every length in the bucket shares: rows past
+        len(tokens) are padding."""
         if router not in ROUTER_LOGITS:
             raise ValueError(f"router {router!r}: one of {ROUTER_LOGITS}")
         n = len(tokens)
@@ -287,8 +304,13 @@ class Reference:
         pad = -(-n // BUCKET) * BUCKET
         t = np.zeros(pad, np.int32)
         t[:n] = tokens
-        logits, margin = _forward(self.spec, self.w, jnp.asarray(t), lower,
-                                  router)
+        return _forward(self.spec, self.w, jnp.asarray(t), lower, router)
+
+    def forward(self, tokens, lower: bool = False, router: str = "f32"):
+        """(len(tokens), vocab) float32 logits on the device, position i
+        predicting token i + 1, and (len(tokens),) router margins."""
+        n = len(tokens)
+        logits, margin = self.padded(tokens, lower, router)
         return logits[:n], margin[:n]
 
     def logits(self, tokens, lower: bool = False, router: str = "f32"):
@@ -302,6 +324,9 @@ def _gaps(logits, picked):
     return best - got
 
 
+_first = jax.jit(partial(jnp.argmax, axis=-1))
+
+
 def served_gaps(ref: Reference, prompt, served, lower_pick: bool = False,
                 router: str = "f32") -> tuple:
     """Gap, under the float32 reference, between the best logit and the
@@ -310,17 +335,23 @@ def served_gaps(ref: Reference, prompt, served, lower_pick: bool = False,
     `lower_pick` the token compared at each position is the one the
     lower-precision control puts first, not the served one; with
     `router` "bf16", the one the reference with a bfloat16 router puts
-    first (the witness)."""
+    first (the witness).
+
+    Every device program works at the padded length and the served
+    positions are cut out on the host, so a new length compiles
+    nothing."""
     prompt = np.asarray(prompt, np.int32)
     served = np.asarray(served, np.int32)
     seq = np.concatenate([prompt, served[:-1]])
-    at = len(prompt) - 1
-    lg, margin = ref.forward(seq)
-    picked = jnp.asarray(served)
+    at, n = len(prompt) - 1, len(seq)
+    lg, margin = ref.padded(seq)
     if lower_pick or router != "f32":
-        picked = jnp.argmax(ref.logits(seq, lower=lower_pick,
-                                       router=router)[at:], axis=-1)
-    return np.asarray(_gaps(lg[at:], picked)), np.asarray(margin[at:])
+        picked = _first(ref.padded(seq, lower=lower_pick, router=router)[0])
+    else:
+        picked = np.zeros(lg.shape[0], np.int32)
+        picked[at:n] = served
+    return (np.asarray(_gaps(lg, jnp.asarray(picked)))[at:n],
+            np.asarray(margin)[at:n])
 
 
 @partial(jax.jit, static_argnums=(0,))

@@ -56,6 +56,29 @@ def test_gaps_of_greedy_tokens_are_zero(ref):
     assert served_gaps(ref, prompt, bad)[0].min() > 0
 
 
+@pytest.mark.parametrize("lower_pick, router", [
+    (False, "f32"), (True, "f32"), (False, "bf16")],
+    ids=["served", "control", "witness"])
+def test_served_gaps_read_as_at_the_request_length(ref, lower_pick,
+                                                   router):
+    """The check cuts the served positions out of the padded programs'
+    output on the host; its numbers are those of the same programs read
+    at the request's own length, bit for bit."""
+    rng = np.random.default_rng(5)
+    prompt = rng.integers(0, CFG["vocab_size"], 37)
+    served = rng.integers(0, CFG["vocab_size"], 9)
+    seq = np.concatenate([prompt, served[:-1]])
+    at = len(prompt) - 1
+    lg, margin = ref.forward(seq)
+    picked = jnp.asarray(served)
+    if lower_pick or router != "f32":
+        picked = jnp.argmax(ref.logits(seq, lower=lower_pick,
+                                       router=router)[at:], axis=-1)
+    g, mg = served_gaps(ref, prompt, served, lower_pick, router)
+    assert np.array_equal(g, np.asarray(_gaps(lg[at:], picked)))
+    assert np.array_equal(mg, np.asarray(margin[at:]))
+
+
 def test_control_is_lower_precision(ref):
     toks = np.arange(30) % CFG["vocab_size"]
     hi = np.asarray(ref.logits(toks))
@@ -184,7 +207,8 @@ def test_phi_layout_at_tiny_size(phi):
 def test_phi_widths_file_passes_dims():
     m = dims(json.loads((DATA / "phimoe-widths.json").read_text()))
     assert (m["d"], m["f"], m["e"], m["k"], m["hd"], m["vpad"]) \
-        == (4096, 6400, 16, 2, 128, 32128)
+        == (4096, 6400, 8, 2, 128, 32128)
+    assert (m["routed"], m["first"], m["layers"]) == (16, 0, 4)
     assert (m["norm"], m["router"], m["jitter"]) \
         == ("layernorm", "sparsemixer", 0.01)
 

@@ -19,6 +19,16 @@ vocabulary as the head is. Those biases are large enough that leaving
 any one of them out moves the served tokens past a check's limit (std
 `BIAS_STD`). The extra leaves draw from a key folded off the seed's
 key, so the leaves of Mixtral's layout are the same for every layout.
+
+Experts held of those routed: `n_routed_experts` (absent: the file's
+`num_local_experts`) is the router's width, the published count;
+`num_local_experts` is how many experts this chip holds, the ids
+`[first_local_expert, first_local_expert + num_local_experts)`
+(`first_local_expert` absent: 0). The router is made at the routed
+width and expert leaves for the held experts only. A file that states
+`n_routed_experts` draws each expert's leaves from a key folded by its
+id, so expert e's leaves are the same whichever share holds it; a file
+without the key draws them all at once, as Mixtral's pins have them.
 """
 from __future__ import annotations
 
@@ -62,6 +72,19 @@ def frozen(v):
     return v
 
 
+def experts_of(c: dict) -> tuple:
+    """(routed, held, first) experts of a file's MoE layers."""
+    held = c["num_local_experts"]
+    return (c.get("n_routed_experts", held), held,
+            c.get("first_local_expert", 0))
+
+
+def is_share(c: dict) -> bool:
+    """Whether the file holds fewer experts than it routes to."""
+    routed, held, _ = experts_of(c)
+    return held < routed
+
+
 def _layout(c: dict, hd: int) -> dict:
     """The file's layout keys, checked; a value the weights and the
     reference do not build is refused rather than served as another."""
@@ -69,6 +92,12 @@ def _layout(c: dict, hd: int) -> dict:
 
     def refuse(why: str):
         raise ValueError(f"unsupported layout: {why}")
+    routed, held, first = experts_of(c)
+    if not all(isinstance(v, int) for v in (routed, held, first)) \
+            or held < 1 or first < 0 or first + held > routed \
+            or routed < c["num_experts_per_tok"]:
+        refuse(f"experts {first}..{first + held - 1} held of {routed} "
+               "routed")
     if lay["norm"] not in NORMS:
         refuse(f"norm {lay['norm']!r}")
     for key in ("attention_bias", "lm_head_bias"):
@@ -96,10 +125,13 @@ def dims(c: dict) -> dict:
     """The sizes and the layout the weights and the reference need, from
     a config file."""
     hd = c["hidden_size"] // c["num_attention_heads"]
+    routed, _, first = experts_of(c)
     return {
         "d": c["hidden_size"], "f": c["intermediate_size"],
         "h": c["num_attention_heads"], "kv": c["num_key_value_heads"],
         "hd": hd, "e": c["num_local_experts"],
+        "routed": routed, "first": first,
+        "per_expert_keys": "n_routed_experts" in c,
         "k": c["num_experts_per_tok"], "layers": c["num_hidden_layers"],
         "vocab": c["vocab_size"], "vpad": padded_vocab(c["vocab_size"]),
         **_layout(c, hd),
@@ -126,17 +158,23 @@ def _make(spec: tuple, key):
             "wv": _normal(next(ks), (n, d, kv * hd), 1 / math.sqrt(d), dt),
             "wo": _normal(next(ks), (n, h * hd, d), 1 / math.sqrt(h * hd),
                           dt)}
-    experts = {
-        "w_gate": _normal(next(ks), (n, e, d, f), 1 / math.sqrt(d), dt),
-        "w_up": _normal(next(ks), (n, e, d, f), 1 / math.sqrt(d), dt),
-        "w_down": _normal(next(ks), (n, e, f, d), 1 / math.sqrt(f), dt)}
+    shapes = {"w_gate": ((d, f), d), "w_up": ((d, f), d),
+              "w_down": ((f, d), f)}
+    if m["per_expert_keys"]:
+        for _ in shapes:                  # the other leaves' keys stay
+            next(ks)
+        experts = _held_experts(key, shapes, m)
+    else:
+        experts = {name: _normal(next(ks), (n, e, *shape),
+                                 1 / math.sqrt(fan), dt)
+                   for name, (shape, fan) in shapes.items()}
     vmask = (jnp.arange(m["vpad"]) < m["vocab"])
     embed = _normal(next(ks), (m["vpad"], d), 1.0, dt) * vmask[:, None]
     head = _normal(next(ks), (d, m["vpad"]), 1 / math.sqrt(d), dt) \
         * vmask[None, :]
     layer = {"norm1": norm((n, d)), "attn": attn, "norm2": norm((n, d)),
              "moe": {"router": {"w_gate": _normal(
-                 next(ks), (n, d, e), 1 / math.sqrt(d), dt)},
+                 next(ks), (n, d, m["routed"]), 1 / math.sqrt(d), dt)},
                  "experts": experts}}
     w = {"embed": embed.astype(dt), "layers": [layer],
          "final_norm": norm((d,)), "head": head.astype(dt)}
@@ -155,6 +193,21 @@ def _make(spec: tuple, key):
         w["head_bias"] = _normal(next(kx), (m["vpad"],), BIAS_STD, dt) \
             * vmask.astype(dt)
     return w
+
+
+def _held_experts(key, shapes: dict, m: dict) -> dict:
+    """Expert leaves (layers, held, ...) of the held experts, expert x's
+    from the key folded by x, made one expert at a time."""
+    n, dt = m["layers"], jnp.bfloat16
+    ids = m["first"] + jnp.arange(m["e"])
+
+    def one(x):
+        kx = jax.random.split(
+            jax.random.fold_in(jax.random.fold_in(key, 2), x), len(shapes))
+        return {name: _normal(k, (n, *shape), 1 / math.sqrt(fan), dt)
+                for k, (name, (shape, fan)) in zip(kx, shapes.items())}
+    return {name: jnp.moveaxis(w, 0, 1)
+            for name, w in jax.lax.map(one, ids).items()}
 
 
 def make_weights(c: dict, seed: int):
